@@ -357,20 +357,23 @@ ZOO_POOL = 96
 ZOO_SHAPES = (1, 2, 4, 8)
 
 def zoo_pass(devs, label):
-    sched = Scheduler(devs, policy="least_loaded")
     engines, inners = [], []
     for i, (cfg, params) in enumerate(zip(ZOO_CFGS, ZOO_PARAMS)):
+        # Each request prefills on the device it is placed on, so every
+        # (rows, device) prefill shape compiles once; a round-robin
+        # scheduler per engine makes placement repeat from pass to pass,
+        # so the warm pass compiles every shape the measured passes use.
         eng = PagedServeEngine.from_config(
             cfg, params=params, devices=devs, max_seq_len=48,
-            pool_pages=ZOO_POOL, scheduler=sched,
+            pool_pages=ZOO_POOL, scheduler=Scheduler(devs, policy="round_robin"),
             prefill=LanePolicy(max_batch=8, max_delay_s=0.02, token_budget=512),
             decode=LanePolicy(max_batch=8, max_delay_s=0.02),
             decode_shapes=ZOO_SHAPES,
             name=f"fig9-zoo-{label}-m{i}")
         inner = eng.decode_fn
-        def wrapped(ks, vs, state, tokens, positions, tables, lengths, _in=inner):
+        def wrapped(params, ks, vs, state, tokens, positions, tables, lengths, _in=inner):
             _occupy(_dev_of(ks), tokens.shape[0] * _TOK_S)
-            return _in(ks, vs, state, tokens, positions, tables, lengths)
+            return _in(params, ks, vs, state, tokens, positions, tables, lengths)
         eng.decode_fn = wrapped
         engines.append(eng)
         inners.append(inner)
@@ -385,7 +388,7 @@ def zoo_pass(devs, label):
     # sentinel, never read back).
     for eng, inner in zip(engines, inners):
         spec = eng.kv.spec
-        st = eng.prefill_fn(np.ones((1, 4), np.int32), None)[2]
+        st = eng.prefill_fn(eng.weights[devs[0].key], np.ones((1, 4), np.int32), None)[2]
         row = (None if st is None
                else jax.tree_util.tree_map(lambda a: np.asarray(a)[0], st))
         sh = (spec.layers, ZOO_POOL, spec.page_size, spec.kv_heads,
@@ -396,7 +399,7 @@ def zoo_pass(devs, label):
             for b in ZOO_SHAPES:
                 stb = (None if row is None else jax.tree_util.tree_map(
                     lambda a, _b=b: np.stack([a] * _b), row))
-                kz, vz, _, _ = inner(kz, vz, stb, np.zeros(b, np.int32),
+                kz, vz, _, _ = inner(eng.weights[d.key], kz, vz, stb, np.zeros(b, np.int32),
                                      np.zeros(b, np.int32),
                                      np.zeros((b, eng.max_pages), np.int32),
                                      np.zeros(b, np.int32))

@@ -17,14 +17,24 @@ transfer–compute overlap win, the batching throughput win and the
 kill-and-recover training property are all tracked per-PR.
 
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig3,...]
+
+One process per chip: this script never imports JAX.  Each module runs
+in a process of its own, one at a time, so at most one process holds the
+accelerator (some figures start their own JAX child and must find the
+chip free).  All of them share the persistent compilation cache that
+``enable_compile_cache`` chooses.
 """
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
+import os
+import subprocess
 import sys
-import traceback
+
+from repro.launch.compile_cache import enable_compile_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 MODULES = [
     ("overhead", "benchmarks.overhead"),
@@ -60,6 +70,26 @@ def _fig6_speedups(rows) -> None:
             r["derived"] = f"{d};speedup_vs_dev1={b / r['s']:.2f}" if d else f"speedup_vs_dev1={b / r['s']:.2f}"
 
 
+def _run_module(modname: str, quick: bool) -> "list[dict]":
+    """Run one module's ``run(quick)`` in a fresh process; its rows come
+    back as JSON on the last stdout line."""
+    code = (
+        "import importlib, json\n"
+        f"rows = importlib.import_module({modname!r}).run(quick={quick!r})\n"
+        "print('ROWS ' + json.dumps(rows), flush=True)\n"
+    )
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(ROOT, "src"), ROOT, env.get("PYTHONPATH")) if p)
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env, cwd=ROOT)
+    lines = [l for l in proc.stdout.splitlines() if l.startswith("ROWS ")]
+    if proc.returncode != 0 or not lines:
+        err = (proc.stderr.strip().splitlines() or ["no output"])[-1]
+        raise RuntimeError(f"exit {proc.returncode}: {err}")
+    return json.loads(lines[-1][len("ROWS "):])
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", help="reduced sizes/iterations")
@@ -67,14 +97,14 @@ def main() -> None:
     args = ap.parse_args()
     only = {t for t in args.only.split(",") if t}
 
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = 0
     for tag, modname in MODULES:
         if only and tag not in only:
             continue
         try:
-            mod = importlib.import_module(modname)
-            rows = mod.run(quick=args.quick)
+            rows = _run_module(modname, args.quick)
             if tag == "fig6":
                 _fig6_speedups(rows)
             # Subprocess-based modules report breakage as a */FAILED data
@@ -102,9 +132,10 @@ def main() -> None:
                 }
                 with open(json_out, "w") as fh:
                     json.dump(payload, fh, indent=2)
-        except Exception:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001
             failed += 1
-            print(f"{tag}/CRASHED,-1,{traceback.format_exc(limit=3).splitlines()[-1]}", flush=True)
+            msg = str(e).replace(",", ";")
+            print(f"{tag}/CRASHED,-1,{msg}", flush=True)
     sys.exit(1 if failed else 0)
 
 

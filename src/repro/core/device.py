@@ -316,8 +316,9 @@ class RemoteDevice:
     order per remote device, and the scheduler's ``load()`` signal),
     ``load()``, ``resident_bytes()``, ``capability()``, plus ``alive()``
     (heartbeat-fed; a dead locality is excluded from placement).
-    ``jax_device`` is a *local staging anchor*: values bound for this
-    device are normalized onto it before they are shipped in a parcel.
+    ``jax_device`` is a *local staging anchor* (the host CPU device):
+    values bound for this device are normalized onto it before they are
+    shipped in a parcel.
     """
 
     is_remote_proxy = True
@@ -330,7 +331,9 @@ class RemoteDevice:
         self.key = f"L{locality_id}/{remote_key}"
         self._platform = platform
         self._capability = tuple(capability)
-        self.jax_device = jax.devices()[0]  # staging anchor, not the executor
+        # Staging anchor, not the executor: values bound for the remote
+        # device are staged in host memory, never on a local accelerator.
+        self.jax_device = jax.devices("cpu")[0]
         rt = get_runtime()
         self.ops_queue: WorkQueue = rt.queue(f"parcel-ops:{self.key}")
         self.compile_queue: WorkQueue = rt.queue(f"parcel-compile:{self.key}")

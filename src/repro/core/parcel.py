@@ -1074,7 +1074,14 @@ def _cluster_worker_main(locality_id: int, rx, tx, shm_replies: bool = True) -> 
     """
     import concurrent.futures as _cf
 
+    import jax
+
     from repro.core import agas
+
+    # A worker is a host locality.  Accelerators belong to the parent (one
+    # process per chip), so the worker runs on the CPU backend and never
+    # loads the accelerator's runtime library.
+    jax.config.update("jax_platforms", "cpu")
 
     txlock = threading.Lock()  # replies come from the pool AND the rx loop
 

@@ -68,20 +68,12 @@ def pin_specs(specs, jax_device) -> list:
     """``ShapeDtypeStruct``s with shardings pinned to one device.
 
     Pinned lowering is what makes a launch execute where its program (or
-    graph segment) lives instead of on XLA's default device; older jax
-    without sharding-carrying specs falls back to default placement.
-    Shared by ``Program.build`` and graph segment compilation so the
-    compat behavior cannot diverge between the two launch paths.
+    graph segment) lives instead of on XLA's default device.  Shared by
+    ``Program.build`` and graph segment compilation so the two launch
+    paths pin the same way.
     """
-    specs = [
-        s if isinstance(s, jax.ShapeDtypeStruct) else jax.ShapeDtypeStruct(s.shape, s.dtype)
-        for s in specs
-    ]
-    try:
-        sharding = jax.sharding.SingleDeviceSharding(jax_device)
-        return [jax.ShapeDtypeStruct(sp.shape, sp.dtype, sharding=sharding) for sp in specs]
-    except (AttributeError, TypeError):  # older jax: default placement
-        return specs
+    sharding = jax.sharding.SingleDeviceSharding(jax_device)
+    return [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding) for s in specs]
 
 
 def _normalize_dim(d) -> "tuple[int, ...] | None":

@@ -71,7 +71,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, bq, bk, 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention_bhsd(
-    q, k, v, *, causal: bool = True, bq: int = 128, bk: int = 128, interpret: bool = True
+    q, k, v, *, interpret: bool, causal: bool = True, bq: int = 128, bk: int = 128,
 ):
     """q: (B, H, Sq, D); k/v: (B, K, Skv, D), H % K == 0 -> (B, H, Sq, D)."""
     B, H, Sq, D = q.shape

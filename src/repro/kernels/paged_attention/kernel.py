@@ -82,7 +82,7 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_bhd(q, k_pages, v_pages, page_table, lengths, *,
-                        interpret: bool = True):
+                        interpret: bool):
     """q: (B, H, D); k/v_pages: (N, P, K, D), H % K == 0;
     page_table: (B, M) int32; lengths: (B,) int32 -> (B, H, D)."""
     B, H, D = q.shape

@@ -21,7 +21,7 @@ def _map_kernel(x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def partition_map(x, *, block: int = 8192, interpret: bool = True):
+def partition_map(x, *, block: int = 8192, interpret: bool):
     n = x.shape[0]
     assert n % block == 0, (n, block)
     return pl.pallas_call(

@@ -61,7 +61,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *, L):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, *, interpret: bool, chunk: int = 64):
     """x: (G, S, P); dt: (G, S); A: (G,); B/C: (G, S, N) -> (G, S, P)."""
     G, S, P = x.shape
     N = B.shape[-1]

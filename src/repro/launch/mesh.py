@@ -9,24 +9,16 @@ FSDP all-reduce over DCI.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n: int) -> dict:
-    # jax.sharding.AxisType landed in newer jax; older versions default all
-    # axes to Auto anyway, so omit the kwarg when it doesn't exist.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (CPU) devices this host exposes —
     used by examples/tests; same axis names as production."""
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_type_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2)

@@ -67,7 +67,7 @@ The model **zoo** rides the richer ``contract="zoo"`` (DESIGN.md §17),
 wired by ``PagedServeEngine.from_config(cfg)`` from the uniform
 ``repro.models.model.paged_surface`` triple:
 
-``prefill_fn(tokens, extras)``
+``prefill_fn(params, tokens, extras)``
     ``-> (k, v, state, last_logits)`` with k/v ``(B, L, T', K, D)`` —
     ``T'`` may exceed the prompt length (hybrid meta/register tokens
     page in too; the engine pages ``k.shape[2]`` tokens) — ``state`` an
@@ -76,10 +76,17 @@ wired by ``PagedServeEngine.from_config(cfg)`` from the uniform
     ``last_logits`` ``(B, V)``: the engine samples the first token
     host-side.  ``extras`` carries modality inputs (whisper frames),
     stacked from each request's ``submit(..., extras=...)``.
-``decode_fn(k_pages, v_pages, state, tokens, positions, tables, lengths)``
+``decode_fn(params, k_pages, v_pages, state, tokens, positions, tables, lengths)``
     ``-> (k_pages, v_pages, state, logits)`` — one ragged step over the
     pools plus the batch's stacked resident state; ``logits`` ``(B, V)``
     come back to the host for sampling.
+
+``params`` is an executable argument, never a closure constant (a
+captured array lowers into the program as a constant — gigabytes per
+compiled shape at published widths).  The engine keeps one replica per
+pool device (``weights``, keyed by device key): a prefill runs on the
+device its request was placed on, with that device's replica, and each
+decode lane steps with its own device's replica.
 
 Resident state spills, migrates and ships with the sequence's pages
 (``SeqPages.set_state`` folds its bytes into the AGAS record — the §14
@@ -239,6 +246,21 @@ def _pow2_pad_idx(idx: np.ndarray) -> np.ndarray:
     if want == n:
         return idx
     return np.concatenate([idx, np.repeat(idx[-1:], want - n)])
+
+
+@functools.cache
+def zoo_steps(cfg):
+    """``(paged_spec, prefill, decode)`` for a zoo config: the family's
+    paged surface with ``cfg`` bound and ``params`` left as the first
+    argument, jitted once per config so every engine and worker over the
+    same config shares its compiled executables.  ``decode`` donates the
+    two slabs (arguments 1 and 2), keeping the page update in place."""
+    from repro.models.model import paged_surface
+
+    spec_fn, prefill_fn, decode_fn = paged_surface(cfg)
+    pre = jax.jit(functools.partial(prefill_fn, cfg))
+    dec = jax.jit(functools.partial(decode_fn, cfg), donate_argnums=(1, 2))
+    return spec_fn, pre, dec
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -791,10 +813,15 @@ class PagedServeEngine:
                  max_queue: int = 512, rebalance_every: int = 32,
                  decode_shapes: "Sequence[int] | None" = None,
                  contract: str = "legacy",
+                 weights: "dict | None" = None,
                  name: str = "paged"):
         if contract not in ("legacy", "zoo"):
             raise ValueError(f"contract must be 'legacy' or 'zoo', got {contract!r}")
+        if contract == "zoo" and (weights is None or set(weights) != set(kv.pools)):
+            raise ValueError("the zoo contract needs one weights replica per pool device")
         self.kv = kv
+        # device key -> that device's params replica (zoo contract).
+        self.weights = weights
         self.prefill_fn = prefill_fn
         self.decode_fn = decode_fn
         self.contract = contract
@@ -870,10 +897,12 @@ class PagedServeEngine:
         ``paged_decode_step``, ``contract="zoo"``.  ``params`` defaults
         to ``init(cfg, PRNGKey(seed))`` — two localities building from
         the same seed hold bit-identical weights, which is what lets a
-        shipped sequence resume decoding elsewhere."""
-        from repro.models.model import get_model, paged_surface
+        shipped sequence resume decoding elsewhere.  The weights are
+        copied once to every pool device and passed to the jitted steps
+        as arguments."""
+        from repro.models.model import get_model
 
-        spec_fn, prefill_fn, decode_fn = paged_surface(cfg)
+        spec_fn, pre, dec = zoo_steps(cfg)
         spec = spec_fn(cfg)
         if params is None:
             params = get_model(cfg).init(cfg, jax.random.PRNGKey(int(seed)))
@@ -881,19 +910,11 @@ class PagedServeEngine:
                           pool_bytes=pool_bytes)
         if max_seq_len is None:
             max_seq_len = 16 * spec.page_size
-
-        @jax.jit
-        def pre(tokens, extras):
-            return prefill_fn(cfg, params, tokens, extras)
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def dec(ks, vs, state, tokens, positions, tables, lengths):
-            return decode_fn(cfg, params, ks, vs, state, tokens,
-                             positions, tables, lengths)
-
+        weights = {key: jax.device_put(params, pool.device.jax_device)
+                   for key, pool in kv.pools.items()}
         kw.setdefault("name", f"paged-{getattr(cfg, 'name', cfg.family)}")
         return cls(kv, pre, dec, max_seq_len=int(max_seq_len),
-                   contract="zoo", **kw)
+                   contract="zoo", weights=weights, **kw)
 
     # -- submission ----------------------------------------------------------
 
@@ -1033,6 +1054,29 @@ class PagedServeEngine:
                         self._prefill_done(r)
 
     def _run_prefill(self, group: "list[_PagedRequest]") -> None:
+        """Place every request of an equal-length group, then prefill.  Zoo
+        steps run each device's share of the group on that device, with
+        its weights replica; the legacy toy steps carry their own weights
+        and run the whole group at once."""
+        sched = self._scheduler_for()
+        homes = [self._prefill_home(sched.select(args=())) for _ in group]
+        if self.contract == "legacy":
+            self._prefill_on(group, homes)
+            return
+        for home in dict.fromkeys(homes):
+            share = [i for i, h in enumerate(homes) if h is home]
+            self._prefill_on([group[i] for i in share], [home] * len(share))
+
+    def _prefill_home(self, dev):
+        """The device a placed request prefills on: the scheduler's pick
+        when it holds a pool of this engine, else the pool device with the
+        most free pages (the scheduler may span a wider fleet)."""
+        pool = self.kv.pools.get(dev.key)
+        if pool is None:
+            pool = max(self.kv.pools.values(), key=lambda p: p.num_free)
+        return pool.device
+
+    def _prefill_on(self, group: "list[_PagedRequest]", homes: list) -> None:
         batch = np.stack([r.tokens for r in group])  # (B, T) — equal-T: no padding
         state = None
         if self.contract == "zoo":
@@ -1040,7 +1084,11 @@ class PagedServeEngine:
             if group[0].extras is not None:
                 extras = {key: np.stack([np.asarray(r.extras[key]) for r in group])
                           for key in group[0].extras}
-            k, v, state, logits = self.prefill_fn(batch, extras)
+            # Committed inputs put the computation on ``home``, next to
+            # that device's weights replica.
+            home = homes[0]  # one device per zoo call (see _run_prefill)
+            on_home = jax.device_put((batch, extras), home.jax_device)
+            k, v, state, logits = self.prefill_fn(self.weights[home.key], *on_home)
             logits = np.asarray(logits)
             if state is not None:
                 state = jax.tree_util.tree_map(np.asarray, state)
@@ -1057,15 +1105,13 @@ class PagedServeEngine:
         # Page k.shape[2] tokens, not the prompt length: hybrid archs
         # prepend meta/register tokens whose KV pages in with the prompt.
         Tp = k.shape[2]
-        sched = self._scheduler_for()
         done = _now()
         with self._m_lock:
             self._prefill_batches += 1
             self._prefill_tokens += batch.size
             self._prefill_rows += len(group)
         for i, req in enumerate(group):
-            dev = sched.select(args=())
-            pool = self._pool_with_room(dev, self.kv.spec.pages_for(Tp) + 1)
+            pool = self._pool_with_room(homes[i], self.kv.spec.pages_for(Tp) + 1)
             req.seq = self.kv.new_seq(pool.device)
             # k[i]: (L, T', Kh, D) — the whole prompt pages in as one write.
             self.kv.append(req.seq, k[i], v[i])
@@ -1185,6 +1231,8 @@ class PagedServeEngine:
         with self._lane_lock:
             m["active_by_device"] = {
                 k: lane.active_count() for k, lane in self._lanes.items()}
+            m["decode_steps_by_device"] = {
+                k: lane.steps for k, lane in self._lanes.items()}
         return m
 
     def __repr__(self) -> str:
@@ -1237,6 +1285,11 @@ class _DecodeLane:
     def active_count(self) -> int:
         with self._cv:
             return len(self._inbox) + len(self._active)
+
+    @property
+    def steps(self) -> int:
+        """Decode steps this lane has run."""
+        return self._steps
 
     def close(self) -> None:
         with self._cv:
@@ -1358,7 +1411,8 @@ class _DecodeLane:
                 with pool.lock:
                     ks, vs = pool.arrays()
                     k2, v2, st2, logits = eng.decode_fn(
-                        ks, vs, state, tokens, lens, tbl, lens)
+                        eng.weights[self.device.key], ks, vs, state, tokens,
+                        lens, tbl, lens)
                     logits = np.asarray(logits)  # sync before the slabs swap
                     pool.set_arrays(k2, v2)
                 if st2 is not None:
@@ -1482,8 +1536,9 @@ _WORKERS: "dict[str, dict]" = {}
 def _worker_ctx(payload: dict) -> dict:
     """Decode-side context for one shipped-page stream, built once per
     ``name`` on this locality and cached: smoke'd (or full) config,
-    seed-derived params, a single-device ``PagedKVCache`` and the jitted
-    slab-donating decode step."""
+    seed-derived params placed on the worker's device, a single-device
+    ``PagedKVCache`` and the shared jitted decode step (params passed as
+    an argument)."""
     name = payload["name"]
     with _WORKER_LOCK:
         ctx = _WORKERS.get(name)
@@ -1492,25 +1547,21 @@ def _worker_ctx(payload: dict) -> dict:
         from repro.configs import get_config
         from repro.configs import smoke as _smoke
         from repro.core.device import get_all_devices
-        from repro.models.model import get_model, paged_surface
+        from repro.models.model import get_model
 
         cfg = get_config(payload["config"])
         if payload.get("smoke", True):
             cfg = _smoke(cfg)
-        spec_fn, _, decode_fn = paged_surface(cfg)
-        params = get_model(cfg).init(
-            cfg, jax.random.PRNGKey(int(payload.get("seed", 0))))
+        spec_fn, _, dec = zoo_steps(cfg)
         devs = list(get_all_devices().get())
         dev = devs[int(payload.get("device_index", 0)) % len(devs)]
+        params = jax.device_put(
+            get_model(cfg).init(cfg, jax.random.PRNGKey(int(payload.get("seed", 0)))),
+            dev.jax_device)
         kv = PagedKVCache(spec_fn(cfg), devices=[dev],
                           pool_pages=payload.get("pool_pages"))
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def dec(ks, vs, state, tokens, positions, tables, lengths):
-            return decode_fn(cfg, params, ks, vs, state, tokens,
-                             positions, tables, lengths)
-
-        ctx = _WORKERS[name] = {"cfg": cfg, "kv": kv, "dev": dev, "dec": dec}
+        ctx = _WORKERS[name] = {"cfg": cfg, "kv": kv, "dev": dev, "dec": dec,
+                                "params": params}
         return ctx
 
 
@@ -1548,7 +1599,7 @@ def paged_worker_decode(payload: dict) -> np.ndarray:
             with pool.lock:
                 ks, vs = pool.arrays()
                 k2, v2, st2, logits = ctx["dec"](
-                    ks, vs, state, tokens, lens, tbl, lens)
+                    ctx["params"], ks, vs, state, tokens, lens, tbl, lens)
                 logits = np.asarray(logits)
                 pool.set_arrays(k2, v2)
             if st2 is not None:

@@ -314,7 +314,8 @@ class LocalWorker:
         if self.device is None:
             from repro.core.device import get_all_devices
 
-            self.device = get_all_devices().get()[0]
+            devs = get_all_devices().get()
+            self.device = devs[self.wid % len(devs)]
         return self.device
 
     def alive(self) -> bool:

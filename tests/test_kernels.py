@@ -44,7 +44,7 @@ def test_all_kernels_aggregates_every_package():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("n,block", [(64, 16), (256, 64), (1024, 128), (4096, 1024)])
+@pytest.mark.parametrize("n,block", [(2048, 2048), (4096, 2048), (16384, 4096), (65536, 8192)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_stencil_matches_ref(n, block, dtype):
     x = jax.random.normal(jax.random.key(n), (n,), dtype)
@@ -58,7 +58,7 @@ def test_stencil_matches_ref(n, block, dtype):
 @settings(max_examples=10, deadline=None)
 @given(
     nb=st.integers(2, 8),
-    block=st.sampled_from([8, 32, 128]),
+    block=st.sampled_from([1024, 2048, 4096]),
     seed=st.integers(0, 2**16),
 )
 def test_stencil_property(nb, block, seed):
@@ -85,7 +85,7 @@ def test_partition_map_matches_ref(n, block, dtype):
 
 def test_partition_map_is_one():
     x = jax.random.normal(jax.random.key(0), (1024,), jnp.float32) * 100
-    np.testing.assert_allclose(np.asarray(partition_map(x, block=256)), 1.0, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(partition_map(x, block=256, interpret=True)), 1.0, rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------

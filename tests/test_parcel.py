@@ -412,6 +412,14 @@ def cluster():
     port.shutdown()
 
 
+def test_cluster_workers_are_cpu_host_localities(cluster):
+    """Workers never take an accelerator (one process per chip): every
+    device a worker reports runs on its CPU backend."""
+    devs = [d for loc in cluster.localities() for d in loc]
+    assert devs and {d.platform for d in devs} == {"cpu"}
+    assert {d.jax_device.platform for d in devs} == {"cpu"}  # host staging anchor
+
+
 def test_cluster_mandelbrot_on_remote_locality_matches_ref(cluster):
     from repro.kernels.mandelbrot.ref import mandelbrot_ref
 
