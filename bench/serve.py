@@ -1,0 +1,225 @@
+"""The system under test, as the benchmark drives it.
+
+``build`` makes the weights from the seed on the device (the family's
+``bench/reference/<family>.py`` ``init``, one jitted call) and builds
+``PagedServeEngine.from_config`` with the configuration file's engine
+settings.  ``Probe`` wraps the engine's two jitted steps in
+``TraceAnnotation`` spans and keeps a small record of every call: the
+prompt length of a prefill, the real rows of a decode step with their
+resident lengths, when each step that makes output tokens is launched and
+how many it makes, and the floating dtypes of the weights, KV pages and
+resident state that the steps are handed and return.  ``warm_up`` runs every prefill and decode shape the
+traffic can reach.  ``OpenLoop`` submits each request at its due time and
+records when its future resolves.
+"""
+from __future__ import annotations
+
+import dataclasses
+import importlib
+import threading
+import time
+
+import jax
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+
+def arch_config(cfg: dict):
+    """The program's ``ArchConfig`` built from the configuration file."""
+    from repro.configs.base import ArchConfig, SSMConfig
+
+    m = dict(cfg["model"])
+    if "ssm" in m:
+        m["ssm"] = SSMConfig(**m["ssm"])
+    return ArchConfig(name=cfg["name"], family=cfg["family"], source=cfg["source"], **m)
+
+
+def reference_module(cfg: dict):
+    return importlib.import_module(f"bench.reference.{cfg['family']}")
+
+
+def work_module(cfg: dict):
+    return importlib.import_module(f"bench.work.{cfg['family']}")
+
+
+def make_weights(cfg: dict, key, device):
+    ref = reference_module(cfg)
+    dtype = np.dtype(cfg["dtype"])
+    sharding = jax.sharding.SingleDeviceSharding(device)
+    return jax.jit(lambda k: ref.init(cfg["model"], k, dtype), out_shardings=sharding)(key)
+
+
+class Probe:
+    """Spans and call records around the engine's jitted steps."""
+
+    def __init__(self):
+        self.prefills: "list[tuple[int, object, object]]" = []   # (T, tokens, last logits)
+        # (padded rows, first page of each real row, resident length of each)
+        self.decodes: "list[tuple[int, np.ndarray, np.ndarray]]" = []
+        self.dtypes: "set[str]" = set()
+        self.produced: "list[tuple[float, int]]" = []  # (launch time, output tokens)
+        self.lock = threading.Lock()
+
+    def clear(self) -> None:
+        with self.lock:
+            self.prefills.clear()
+            self.decodes.clear()
+            self.dtypes.clear()
+            self.produced.clear()
+
+    def _stored(self, *trees) -> None:
+        names = {str(a.dtype) for a in jax.tree_util.tree_leaves(trees)
+                 if jax.numpy.issubdtype(a.dtype, jax.numpy.floating)}
+        with self.lock:
+            self.dtypes |= names
+
+    def wrap(self, eng) -> None:
+        prefill, decode = eng.prefill_fn, eng.decode_fn
+
+        def traced_prefill(params, tokens, extras):
+            with self.lock:
+                i = len(self.prefills)
+                self.prefills.append(None)
+            T = int(tokens.shape[1])
+            self._stored(params)
+            with self.lock:
+                self.produced.append((time.perf_counter(), int(tokens.shape[0])))
+            with TraceAnnotation(f"bench.prefill#{i}:{T}"):
+                out = prefill(params, tokens, extras)
+            self.prefills[i] = (T, tokens, out[3])
+            self._stored(out[:3])
+            return out
+
+        def traced_decode(params, k_pages, v_pages, state, tokens, positions, tables, lengths):
+            # Pad rows repeat the last real row, and each real row's first
+            # page is its own: the distinct first pages count the real rows.
+            first = np.asarray(tables)[:, 0]
+            real = len(np.unique(first))
+            with self.lock:
+                i = len(self.decodes)
+                self.decodes.append((int(first.size), first[:real].copy(),
+                                     np.asarray(lengths)[:real].copy()))
+                self.produced.append((time.perf_counter(), real))
+            self._stored(params, k_pages, v_pages, state)
+            with TraceAnnotation(f"bench.decode#{i}:{tables.shape[0]}"):
+                return decode(params, k_pages, v_pages, state, tokens, positions, tables, lengths)
+
+        eng.prefill_fn, eng.decode_fn = traced_prefill, traced_decode
+
+
+def build(cfg: dict, device, weights, *, name: str):
+    from repro.core import get_all_devices
+    from repro.core.scheduler import Scheduler
+    from repro.serving import LanePolicy, PagedServeEngine
+
+    e = cfg["engine"]
+    devices = [d for d in get_all_devices().get() if d.jax_device == device]
+    return PagedServeEngine.from_config(
+        arch_config(cfg), params=weights, devices=devices,
+        max_seq_len=e["max_seq_len"], pool_bytes=e["pool_bytes"],
+        scheduler=Scheduler(devices, policy="round_robin"),
+        prefill=LanePolicy(max_batch=e["prefill_max_batch"]),
+        decode=LanePolicy(max_batch=e["decode_max_batch"]),
+        decode_shapes=e["decode_shapes"], max_queue=e["max_queue"], name=name)
+
+
+def warm_up(eng, prompt_lengths) -> None:
+    """Run every shape the traffic reaches: prefill at one row for each
+    palette length (and the page write of its prompt) through the engine;
+    the page gather and write of a sequence spilled to the host and
+    fetched back, at every page count it can have; then the decode step at
+    every warm row count."""
+    dev_key, weights = next(iter(eng.weights.items()))
+    pool = eng.kv.pools[dev_key]
+    state_row = None
+    for T in sorted(set(int(t) for t in prompt_lengths)):
+        prompt = np.ones((T,), np.int32)
+        eng.submit(prompt, 2, request_id=-T).get(timeout=1200)
+    # The pool pads a move to a power of two of pages (``_pow2_pad_idx``).
+    least = eng.kv.spec.pages_for(min(prompt_lengths))
+    sizes = {1 << (n - 1).bit_length() for n in range(least, eng.max_pages + 1)}
+    for n in sorted(sizes):
+        pages = list(range(1, min(n, pool.num_pages - 1) + 1))
+        pool.write_pages(pages, *pool.read_pages(pages))
+    T = min(prompt_lengths)
+    on_dev = jax.device_put((np.ones((1, int(T)), np.int32), None), pool.device.jax_device)
+    _, _, state, _ = eng.prefill_fn(weights, *on_dev)
+    if state is not None:
+        state_row = jax.tree_util.tree_map(lambda a: np.zeros(a.shape[1:], np.asarray(a).dtype), state)
+    for B in eng.decode_shapes:
+        tables = np.zeros((B, eng.max_pages), np.int32)
+        lens = np.zeros((B,), np.int32)
+        tokens = np.ones((B,), np.int32)
+        st = None
+        if state_row is not None:
+            st = jax.tree_util.tree_map(lambda a: np.stack([a] * B), state_row)
+        with pool.lock:
+            ks, vs = pool.arrays()
+            k2, v2, _, logits = eng.decode_fn(weights, ks, vs, st, tokens, lens, tables, lens)
+            np.asarray(logits)
+            pool.set_arrays(k2, v2)
+
+
+@dataclasses.dataclass
+class Sent:
+    req: object
+    due: float               # absolute perf_counter time
+    sent: float = 0.0
+    done: "float | None" = None
+    future: object = None
+
+
+class OpenLoop:
+    """Submits each request at its due time (absolute ``origin + due_s``)."""
+
+    def __init__(self, eng, requests, origin: float):
+        self.eng = eng
+        self.sent = [Sent(r, origin + r.due_s) for r in requests]
+        self.next = 0
+        self.samples: "list[tuple[float, int, int]]" = []  # (time, in flight, used pages)
+
+    def _complete(self, s: Sent):
+        def cb(value):
+            with TraceAnnotation("bench.complete"):
+                s.done = time.perf_counter()
+            return value
+        return cb
+
+    def in_flight(self) -> int:
+        return sum(1 for s in self.sent[:self.next] if s.done is None and not s.future.done())
+
+    def run_until(self, t_end: float, pool=None, tick: float = 0.1) -> None:
+        """Submit everything due before ``t_end``; sample the backlog and the
+        page pool at ``tick`` intervals meanwhile."""
+        last = 0.0
+        while True:
+            now = time.perf_counter()
+            if now - last >= tick:
+                used = pool.used_pages if pool is not None else 0
+                self.samples.append((now, self.in_flight(), used))
+                last = now
+            if self.next < len(self.sent) and self.sent[self.next].due < t_end:
+                s = self.sent[self.next]
+                if s.due <= now:
+                    with TraceAnnotation("bench.submit"):
+                        s.sent = time.perf_counter()
+                        s.future = self.eng.submit(s.req.prompt, s.req.max_new, request_id=s.req.rid)
+                        s.future.then(self._complete(s), executor="inline")
+                    self.next += 1
+                    continue
+                wake = min(s.due, last + tick, t_end)
+            else:
+                if now >= t_end:
+                    return
+                wake = min(last + tick, t_end)
+            time.sleep(max(0.0, wake - time.perf_counter()))
+
+    def wait(self, sent, deadline: float) -> None:
+        for s in sent:
+            left = deadline - time.perf_counter()
+            if left <= 0:
+                return
+            try:
+                s.future.get(timeout=left)
+            except Exception:  # noqa: BLE001 - failures are counted, not raised
+                pass
