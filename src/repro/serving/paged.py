@@ -97,6 +97,14 @@ request ``request_id`` draws from
 function of request identity, never of batch composition or fleet size
 (greedy argmax when ``temperature <= 0``).
 
+Spans and counters (``repro.core.trace``) mark the engine's stage
+boundaries in a profiler trace (``paged.prefill.*``, ``paged.kv.*``,
+``paged.decode.*``) and count its work: admissions and their wait, prompt
+and output tokens, decode steps and the rows that sat them out, spilled and
+refetched pages, and the bytes copied between host and device, by stage.
+``PagedServeEngine.counters()`` returns the totals; docs/index.md,
+"Tracing the engine", says what each one means.
+
 Env knobs: ``REPRO_PAGE_SIZE`` (tokens per page, default 16),
 ``REPRO_PAGE_POOL_BYTES`` (per-device pool bytes, default 32 MiB),
 ``REPRO_PREFILL_TOKEN_BUDGET`` (prefill lane batch bound, default 2048),
@@ -104,6 +112,7 @@ Env knobs: ``REPRO_PAGE_SIZE`` (tokens per page, default 16),
 """
 from __future__ import annotations
 
+import collections
 import concurrent.futures as _cf
 import contextlib
 import functools
@@ -112,7 +121,7 @@ import threading
 import time
 import weakref
 from dataclasses import dataclass
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
 import numpy as np
@@ -120,6 +129,7 @@ import numpy as np
 from repro.core import agas
 from repro.core.executor import coalesce
 from repro.core.futures import Future, Promise
+from repro.core.trace import Counters, span
 from repro.serving.engine import EngineClosed, LanePolicy, QueueFull
 
 __all__ = [
@@ -235,6 +245,33 @@ def sample_token(logits, params: "SamplingParams | None",
 _MAX_DECODE_STALLS = 500
 
 
+# Finished requests whose timelines ``PagedServeEngine.metrics()`` reads.
+_RECENT_REQUESTS = 256
+
+
+# Host-device copies are counted where they are made, as ``h2d_bytes`` and
+# ``d2h_bytes`` keyed by stage: sizes reckoned from the arrays' ``nbytes``
+# (pow-2 page padding and pad rows included), not timed transfers.
+
+def _sent(counters: Counters, stage: str, tree) -> None:
+    """Count the host arrays of ``tree`` as copied to the device (by a
+    jitted call or ``device_put``)."""
+    n = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree)
+            if isinstance(a, np.ndarray))
+    if n:
+        counters.add("h2d_bytes", n, key=stage)
+
+
+def _fetched(counters: Counters, stage: str, tree):
+    """``tree`` with every leaf on the host; its device arrays count as
+    copied to the host."""
+    n = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree)
+            if isinstance(a, jax.Array))
+    if n:
+        counters.add("d2h_bytes", n, key=stage)
+    return jax.tree_util.tree_map(np.asarray, tree)
+
+
 def _pow2_pad_idx(idx: np.ndarray) -> np.ndarray:
     """Pad a page-index vector to the next power-of-two length by
     repeating the last entry, bounding the distinct shapes the jitted
@@ -278,15 +315,18 @@ class PagePool:
 
     All slab mutation happens under ``lock`` — the prefill lane (paging
     a prompt in), the decode lane (swapping the stepped slabs back) and
-    the spiller (reading a victim's pages out) race otherwise.
+    the spiller (reading a victim's pages out) race otherwise.  Page
+    moves count their host-device bytes in ``counters``, the owning
+    cache's.
     """
 
-    def __init__(self, device, spec: PageSpec, num_pages: int):
+    def __init__(self, device, spec: PageSpec, num_pages: int, counters: Counters):
         if num_pages < 2:
             raise ValueError("PagePool needs >= 2 pages (page 0 is reserved)")
         self.device = device
         self.spec = spec
         self.num_pages = int(num_pages)
+        self.counters = counters
         shape = (spec.layers, self.num_pages, spec.page_size,
                  spec.kv_heads, spec.head_dim)
         self.k_slab = device.create_buffer(shape, spec.dtype).get()
@@ -355,9 +395,10 @@ class PagePool:
             self.k_slab._set_array(k)
             self.v_slab._set_array(v)
 
-    def write_pages(self, pages: "Sequence[int]", k, v) -> None:
+    def write_pages(self, pages: "Sequence[int]", k, v, stage: str = "other") -> None:
         """Scatter page contents into the slabs: k/v are
         ``(n, L, P, Kh, D)`` host or device arrays, one row per page.
+        ``stage`` names the copy in the byte counters.
 
         Runs through a jitted, slab-donating scatter with the page count
         padded to a power of two (duplicate trailing index, same value —
@@ -367,11 +408,13 @@ class PagePool:
         if n == 0:
             return
         idx = _pow2_pad_idx(np.asarray(pages, np.int32))
-        kk = np.moveaxis(np.asarray(k), 0, 1)
-        vv = np.moveaxis(np.asarray(v), 0, 1)
+        k, v = _fetched(self.counters, stage, (k, v))
+        kk = np.moveaxis(k, 0, 1)
+        vv = np.moveaxis(v, 0, 1)
         if idx.size != n:
             kk = np.concatenate([kk, np.repeat(kk[:, -1:], idx.size - n, axis=1)], axis=1)
             vv = np.concatenate([vv, np.repeat(vv[:, -1:], idx.size - n, axis=1)], axis=1)
+        _sent(self.counters, stage, (idx, kk, vv))
         dev = self.device.jax_device
         with self.lock:
             ks, vs = self.k_slab.array(), self.v_slab.array()
@@ -379,22 +422,25 @@ class PagePool:
             self.k_slab._set_array(_slab_scatter(ks, idxd, jax.device_put(kk, dev)))
             self.v_slab._set_array(_slab_scatter(vs, idxd, jax.device_put(vv, dev)))
 
-    def read_pages(self, pages: "Sequence[int]") -> "tuple[np.ndarray, np.ndarray]":
+    def read_pages(self, pages: "Sequence[int]",
+                   stage: str = "other") -> "tuple[np.ndarray, np.ndarray]":
         """Gather page contents out: ``(n, L, P, Kh, D)`` host arrays.
         Jitted gather, page count padded to a power of two (extra rows
-        sliced off) — same compile-churn guard as ``write_pages``."""
+        sliced off) — same compile-churn guard as ``write_pages``.
+        ``stage`` names the copy in the byte counters."""
         n = len(pages)
         if n == 0:
             sh = (0, self.spec.layers, self.spec.page_size,
                   self.spec.kv_heads, self.spec.head_dim)
             return np.empty(sh, self.spec.dtype), np.empty(sh, self.spec.dtype)
         idx = _pow2_pad_idx(np.asarray(pages, np.int32))
+        _sent(self.counters, stage, idx)
         with self.lock:
             ks, vs = self.k_slab.array(), self.v_slab.array()
             idxd = jax.device_put(idx, self.device.jax_device)
             kg, vg = _slab_gather(ks, idxd), _slab_gather(vs, idxd)
-        return (np.moveaxis(np.asarray(kg), 1, 0)[:n],
-                np.moveaxis(np.asarray(vg), 1, 0)[:n])
+        kg, vg = _fetched(self.counters, stage, (kg, vg))
+        return np.moveaxis(kg, 1, 0)[:n], np.moveaxis(vg, 1, 0)[:n]
 
     def __repr__(self) -> str:
         return (f"PagePool({self.device.key}: {self.used_pages}/"
@@ -485,7 +531,9 @@ class SeqPages:
         with self._lock:
             if self._spilled is not None or not self.pages:
                 return False
-            self._spilled = self.pool.read_pages(self.pages)
+            with span("paged.kv.spill", seq=self.seq_id):
+                self._spilled = self.pool.read_pages(self.pages, stage="spill")
+            self.pool.counters.add("spilled_pages", len(self.pages))
             self.pool.free(self.pages)
             self.pages = []
             agas.registry.update_placement(
@@ -504,7 +552,9 @@ class SeqPages:
                 return
             k, v = self._spilled
             pages = self.pool.alloc(len(k))
-            self.pool.write_pages(pages, k, v)
+            with span("paged.kv.refetch", seq=self.seq_id):
+                self.pool.write_pages(pages, k, v, stage="refetch")
+            self.pool.counters.add("refetched_pages", len(pages))
             self.pages = pages
             self._spilled = None
             dev = self.pool.device
@@ -522,7 +572,8 @@ class SeqPages:
 class PagedKVCache:
     """Fleet-wide paged KV allocator: one ``PagePool`` per device plus
     the sequence lifecycle (``new_seq``/``append``/``free_seq``), pool
-    compaction (``defrag``) and coalesced cross-device ``migrate``."""
+    compaction (``defrag``) and coalesced cross-device ``migrate``.
+    ``counters`` holds the totals of the cache and its pools."""
 
     def __init__(self, spec: PageSpec, devices: "Sequence | None" = None,
                  pool_pages: "int | None" = None,
@@ -536,8 +587,9 @@ class PagedKVCache:
                 pool_bytes = _env_int("REPRO_PAGE_POOL_BYTES", 32 << 20)
             pool_pages = max(2, pool_bytes // spec.page_bytes)
         self.spec = spec
+        self.counters = Counters()
         self.pools: "dict[str, PagePool]" = {
-            d.key: PagePool(d, spec, pool_pages) for d in devices
+            d.key: PagePool(d, spec, pool_pages, self.counters) for d in devices
         }
         self._seq_lock = threading.Lock()
         self._next_seq = 0
@@ -564,11 +616,10 @@ class PagedKVCache:
         tail pages are zero-padded to the page boundary (masked by
         ``length`` at attention time)."""
         seq.ensure_resident()
-        k = np.asarray(k)
-        v = np.asarray(v)
-        L, T, Kh, D = k.shape
         P = self.spec.page_size
-        with seq._lock:
+        with seq._lock, span("paged.kv.append", seq=seq.seq_id):
+            k, v = _fetched(self.counters, "prompt_kv", (k, v))
+            L, T, Kh, D = k.shape
             if seq.length % P:
                 raise ValueError(
                     "append must start on a page boundary (decode steps append "
@@ -585,6 +636,7 @@ class PagedKVCache:
                 pages,
                 np.moveaxis(k.reshape(L, n, P, Kh, D), 1, 0),
                 np.moveaxis(v.reshape(L, n, P, Kh, D), 1, 0),
+                stage="prompt_kv",
             )
             seq.pages.extend(pages)
             seq.length += T
@@ -686,6 +738,7 @@ class PagedKVCache:
                         order = np.arange(pool.num_pages, dtype=np.int32)
                         for old, new in mapping.items():
                             order[new] = old
+                        _sent(self.counters, "defrag", (order, order))
                         ks, vs = pool.arrays()
                         pool.set_arrays(ks[:, order], vs[:, order])
                         for s in holders:
@@ -707,9 +760,9 @@ class PagedKVCache:
             seq.ensure_resident()
             src = seq.pool
             with coalesce():
-                k, v = src.read_pages(seq.pages)
+                k, v = src.read_pages(seq.pages, stage="migrate")
                 pages = dst.alloc(len(seq.pages))
-                dst.write_pages(pages, k, v)
+                dst.write_pages(pages, k, v, stage="migrate")
             src.free(seq.pages)
             seq.pool = dst
             seq.pages = pages
@@ -729,10 +782,8 @@ class PagedKVCache:
         serializing megabytes through the control channel."""
         with seq._lock:
             seq.ensure_resident()
-            k, v = seq.pool.read_pages(seq.pages)
-            state = seq.state
-            if state is not None:
-                state = jax.tree_util.tree_map(np.asarray, state)
+            k, v = seq.pool.read_pages(seq.pages, stage="export")
+            state = _fetched(self.counters, "export", seq.state)
             return {"k": k, "v": v, "length": int(seq.length), "state": state}
 
     def import_seq(self, device, payload: dict) -> SeqPages:
@@ -741,11 +792,10 @@ class PagedKVCache:
         bytes re-declared against THIS device) — decode resumes from the
         shipped table as if the prompt had prefilled here."""
         seq = self.new_seq(device)
-        k = np.asarray(payload["k"])
-        v = np.asarray(payload["v"])
+        k, v = _fetched(self.counters, "import", (payload["k"], payload["v"]))
         with seq._lock:
             pages = seq.pool.alloc(len(k))
-            seq.pool.write_pages(pages, k, v)
+            seq.pool.write_pages(pages, k, v, stage="import")
             seq.pages = pages
             seq.length = int(payload["length"])
             if payload.get("state") is not None:
@@ -766,9 +816,24 @@ class PagedKVCache:
         return out
 
 
+class RequestTimeline(NamedTuple):
+    """A finished request, as ``PagedServeEngine.metrics()`` reads it:
+    host times (``time.monotonic``) of its arrival, prefill launch, first
+    and last output token, its token count, and the gaps between its
+    consecutive tokens (steps it sat out included)."""
+
+    rid: int
+    arrived: float
+    launched: float
+    first_token: float
+    last_token: float
+    tokens: int
+    gaps: np.ndarray
+
+
 class _PagedRequest:
     __slots__ = ("tokens", "max_new", "promise", "arrived", "seq", "out",
-                 "started", "first_token_s", "handed_off", "rid", "sampling",
+                 "launched", "token_times", "handed_off", "rid", "sampling",
                  "extras")
 
     def __init__(self, tokens, max_new, promise, arrived, rid=0,
@@ -785,8 +850,8 @@ class _PagedRequest:
         self.extras = extras
         self.seq: "SeqPages | None" = None
         self.out: "list[int]" = []
-        self.started = arrived
-        self.first_token_s: "float | None" = None
+        self.launched = arrived  # prefill launch
+        self.token_times: "list[float]" = []  # host time each of ``out`` was appended
         # True once prefill is done with the request — settled or admitted
         # to a decode lane.  A prefill-batch failure must fail only the
         # requests still owned by prefill: settling an already-admitted
@@ -861,23 +926,25 @@ class PagedServeEngine:
         self._lane_lock = threading.Lock()
         self._lanes: "dict[str, _DecodeLane]" = {}
 
-        # Metrics.
+        # Metrics: the window's counts (``reset_metrics`` zeroes them), the
+        # cache's monotonic counters (shared with its pools; ``counters()``)
+        # with their totals at the window's start, and the timelines of
+        # the most recent finished requests.
         self._m_lock = threading.Lock()
         self._started_at = _now()
         self._submitted = 0
         self._completed = 0
         self._failed = 0
         self._prefill_batches = 0
-        self._prefill_tokens = 0
         self._prefill_rows = 0
         self._prefill_padded = 0
-        self._decode_steps = 0
         self._decode_rows = 0
         self._decode_padded = 0
         self._migrations = 0
-        self._token_lat: "list[float]" = []
-        self._seq_lat: "list[float]" = []
-        self._ttft: "list[float]" = []
+        self._counters = kv.counters
+        self._counted_before: dict = {}
+        self._recent: "collections.deque[RequestTimeline]" = collections.deque(
+            maxlen=_RECENT_REQUESTS)
 
         self._prefill_thread = threading.Thread(
             target=self._prefill_loop, name=f"paged:{name}:prefill", daemon=True)
@@ -956,21 +1023,31 @@ class PagedServeEngine:
         return promise.get_future()
 
     def reset_metrics(self) -> None:
-        """Zero the counters and latency histograms (placement state, warm
-        decode shapes and resident pages are untouched).  Benchmarks call
-        this after a warm-up pass so ``metrics()`` reflects only the
-        measured window — warm-pass XLA compiles would otherwise dominate
-        every latency percentile."""
+        """Zero the counts of ``metrics()`` and drop its record of finished
+        requests (placement state, warm decode shapes, resident pages and
+        the monotonic ``counters()`` are untouched).  Benchmarks call this
+        after a warm-up pass so ``metrics()`` reflects only the measured
+        window — warm-pass XLA compiles would otherwise dominate every
+        latency percentile."""
         with self._m_lock:
             self._started_at = _now()
             self._submitted = self._completed = self._failed = 0
-            self._prefill_batches = self._prefill_tokens = 0
+            self._prefill_batches = 0
             self._prefill_rows = self._prefill_padded = 0
-            self._decode_steps = self._decode_rows = self._decode_padded = 0
+            self._decode_rows = self._decode_padded = 0
             self._migrations = 0
-            self._token_lat.clear()
-            self._seq_lat.clear()
-            self._ttft.clear()
+            self._counted_before = self._counters.snapshot()
+            self._recent.clear()
+
+    def counters(self) -> dict:
+        """Monotonic totals of the engine, its cache and its pools (see
+        docs/index.md, "Tracing the engine"): ``admitted``,
+        ``admission_wait_s``, ``prefill_tokens``, ``output_tokens``,
+        ``decode_steps``, ``decode_active_row_steps``,
+        ``decode_left_out_row_steps``, ``spilled_pages``,
+        ``refetched_pages``, and ``h2d_bytes`` / ``d2h_bytes`` keyed by
+        stage.  A total appears once something has been counted in it."""
+        return self._counters.snapshot()
 
     def __enter__(self) -> "PagedServeEngine":
         return self
@@ -1077,38 +1154,45 @@ class PagedServeEngine:
         return pool.device
 
     def _prefill_on(self, group: "list[_PagedRequest]", homes: list) -> None:
+        c = self._counters
+        launched = _now()
+        for r in group:
+            r.launched = launched
+        c.add("admitted", len(group))
+        c.add("admission_wait_s", sum(launched - r.arrived for r in group))
         batch = np.stack([r.tokens for r in group])  # (B, T) — equal-T: no padding
-        state = None
+        state = extras = None
+        rid = group[0].rid
+        if self.contract == "zoo" and group[0].extras is not None:
+            extras = {key: np.stack([np.asarray(r.extras[key]) for r in group])
+                      for key in group[0].extras}
+        _sent(c, "prefill_tokens", (batch, extras))
+        with span("paged.prefill.step", rid=rid):
+            if self.contract == "zoo":
+                # Committed inputs put the computation on ``home``, next to
+                # that device's weights replica.
+                home = homes[0]  # one device per zoo call (see _run_prefill)
+                on_home = jax.device_put((batch, extras), home.jax_device)
+                k, v, state, logits = self.prefill_fn(self.weights[home.key], *on_home)
+                logits = _fetched(c, "logits", logits)
+                state = _fetched(c, "state", state)
+            else:
+                k, v, nxt = self.prefill_fn(batch)
+                nxt = np.asarray(_fetched(c, "logits", nxt), np.int32)
+        c.add("prefill_tokens", batch.size)
+        with span("paged.prefill.kv_to_host", rid=rid):
+            k, v = _fetched(c, "prompt_kv", (k, v))
         if self.contract == "zoo":
-            extras = None
-            if group[0].extras is not None:
-                extras = {key: np.stack([np.asarray(r.extras[key]) for r in group])
-                          for key in group[0].extras}
-            # Committed inputs put the computation on ``home``, next to
-            # that device's weights replica.
-            home = homes[0]  # one device per zoo call (see _run_prefill)
-            on_home = jax.device_put((batch, extras), home.jax_device)
-            k, v, state, logits = self.prefill_fn(self.weights[home.key], *on_home)
-            logits = np.asarray(logits)
-            if state is not None:
-                state = jax.tree_util.tree_map(np.asarray, state)
             # First token samples host-side at position 0 of each
             # request's own PRNG stream — batch composition cannot leak.
             nxt = np.asarray(
                 [sample_token(logits[i], r.sampling, r.rid, 0)
                  for i, r in enumerate(group)], np.int32)
-        else:
-            k, v, nxt = self.prefill_fn(batch)
-            nxt = np.asarray(nxt, np.int32)
-        k = np.asarray(k)
-        v = np.asarray(v)
         # Page k.shape[2] tokens, not the prompt length: hybrid archs
         # prepend meta/register tokens whose KV pages in with the prompt.
         Tp = k.shape[2]
-        done = _now()
         with self._m_lock:
             self._prefill_batches += 1
-            self._prefill_tokens += batch.size
             self._prefill_rows += len(group)
         for i, req in enumerate(group):
             pool = self._pool_with_room(homes[i], self.kv.spec.pages_for(Tp) + 1)
@@ -1119,8 +1203,8 @@ class PagedServeEngine:
                 req.seq.set_state(
                     jax.tree_util.tree_map(lambda a, i=i: a[i], state))
             req.out.append(int(nxt[i]))
-            req.started = done
-            req.first_token_s = done - req.arrived
+            req.token_times.append(_now())
+            c.add("output_tokens", 1)
             if req.max_new <= 1:
                 self._finish(req)
             else:
@@ -1185,23 +1269,34 @@ class PagedServeEngine:
             req.promise.set_value(np.asarray(req.out, np.int32))
         except _cf.InvalidStateError:
             return
+        times = req.token_times
+        timeline = RequestTimeline(
+            req.rid, req.arrived, req.launched, times[0], times[-1], len(times),
+            np.diff(times)) if times else None
         with self._m_lock:
             self._completed += 1
-            self._seq_lat.append(_now() - req.arrived)
-            if req.first_token_s is not None:
-                self._ttft.append(req.first_token_s)
+            if timeline is not None:
+                self._recent.append(timeline)
 
     # -- metrics -------------------------------------------------------------
 
     @staticmethod
-    def _pct(xs: "list[float]", q: float) -> float:
-        if not xs:
+    def _pct(xs, q: float) -> float:
+        xs = np.sort(np.asarray(xs, np.float64))
+        if not xs.size:
             return 0.0
-        xs = sorted(xs)
-        return xs[int(q * (len(xs) - 1))]
+        return float(xs[int(q * (xs.size - 1))])
 
     def metrics(self) -> dict:
+        """Counts since the last ``reset_metrics`` and latency percentiles
+        over the most recent finished requests (at most
+        ``_RECENT_REQUESTS``): ``ttft_p99_s`` from arrival to first token,
+        ``token_latency_*`` over the gaps between a request's consecutive
+        tokens, steps it sat out included."""
+        counted = self._counters.snapshot()
         with self._m_lock:
+            before = self._counted_before
+            recent = list(self._recent)
             rows = self._prefill_rows + self._decode_rows
             padded = self._prefill_padded + self._decode_padded
             m = {
@@ -1209,17 +1304,20 @@ class PagedServeEngine:
                 "requests_completed": self._completed,
                 "requests_failed": self._failed,
                 "prefill_batches": self._prefill_batches,
-                "prefill_tokens": self._prefill_tokens,
-                "decode_steps": self._decode_steps,
+                "prefill_tokens": (counted.get("prefill_tokens", 0)
+                                   - before.get("prefill_tokens", 0)),
+                "decode_steps": (counted.get("decode_steps", 0)
+                                 - before.get("decode_steps", 0)),
                 "rows": rows,
                 "padded_rows": padded,
                 "padding_waste": (padded / rows) if rows else 0.0,
                 "migrations": self._migrations,
-                "token_latency_p50_s": self._pct(self._token_lat, 0.50),
-                "token_latency_p99_s": self._pct(self._token_lat, 0.99),
-                "ttft_p99_s": self._pct(self._ttft, 0.99),
-                "seq_latency_p99_s": self._pct(self._seq_lat, 0.99),
             }
+        gaps = np.concatenate([t.gaps for t in recent]) if recent else []
+        ttft = [t.first_token - t.arrived for t in recent]
+        m["token_latency_p50_s"] = self._pct(gaps, 0.50)
+        m["token_latency_p99_s"] = self._pct(gaps, 0.99)
+        m["ttft_p99_s"] = self._pct(ttft, 0.99)
         elapsed = max(_now() - self._started_at, 1e-9)
         m["elapsed_s"] = elapsed
         m["seqs_per_s"] = m["requests_completed"] / elapsed
@@ -1324,10 +1422,11 @@ class _DecodeLane:
                 self._active.sort(key=lambda r: r.seq.spilled)
                 cap = pol.max_batch if pol.max_batch is not None else 64
                 batch = self._active[:cap]
+                active = len(self._active)
             if not batch:
                 continue
             try:
-                self._step(batch)
+                self._step(batch, active)
             except BaseException as e:  # noqa: BLE001 - fail the batch, not the lane
                 with self._cv:
                     for r in batch:
@@ -1336,10 +1435,13 @@ class _DecodeLane:
                 for r in batch:
                     eng._finish(r, e)
 
-    def _step(self, batch: "list[_PagedRequest]") -> None:
+    def _step(self, batch: "list[_PagedRequest]", active: int) -> None:
+        """One decode step over the ready members of ``batch``, taken
+        from the lane's ``active`` sequences; the others sit it out."""
         eng = self.engine
         kv = eng.kv
-        t0 = _now()
+        c = eng._counters
+        step = self._steps
         # Page pressure IS the capacity limit on a small fleet: a
         # sequence whose pages cannot be made resident right now is
         # deferred — it stays active and retries as finishing sequences
@@ -1383,21 +1485,27 @@ class _DecodeLane:
                 return
             self._stalls = 0
             batch = ready
-            seqs = [r.seq for r in batch]
-            tbl, lens = kv.table(seqs, eng.max_pages)
-            tokens = np.asarray([r.out[-1] for r in batch], np.int32)
-            # Shape reuse (see class docstring): pad to the nearest warm row
-            # count when that costs less than doubling the batch, else
-            # compile this exact count and make it warm.
-            b_real = len(batch)
-            cand = min((w for w in self._warm if w >= b_real), default=None)
-            want = cand if cand is not None and cand - b_real <= b_real else b_real
-            self._warm.add(want)
-            pad = want - b_real
-            if pad:
-                tbl = np.concatenate([tbl, np.repeat(tbl[-1:], pad, axis=0)])
-                lens = np.concatenate([lens, np.repeat(lens[-1:], pad)])
-                tokens = np.concatenate([tokens, np.repeat(tokens[-1:], pad)])
+            with span("paged.decode.table", step=step):
+                seqs = [r.seq for r in batch]
+                tbl, lens = kv.table(seqs, eng.max_pages)
+                tokens = np.asarray([r.out[-1] for r in batch], np.int32)
+                # Shape reuse (see class docstring): pad to the nearest warm
+                # row count when that costs less than doubling the batch,
+                # else compile this exact count and make it warm.
+                b_real = len(batch)
+                cand = min((w for w in self._warm if w >= b_real), default=None)
+                want = cand if cand is not None and cand - b_real <= b_real else b_real
+                self._warm.add(want)
+                pad = want - b_real
+                if pad:
+                    tbl = np.concatenate([tbl, np.repeat(tbl[-1:], pad, axis=0)])
+                    lens = np.concatenate([lens, np.repeat(lens[-1:], pad)])
+                    tokens = np.concatenate([tokens, np.repeat(tokens[-1:], pad)])
+            # Host operands ride the call uncommitted: the computation
+            # follows the committed slabs to this lane's device, and the C++
+            # dispatch path moves four tiny arrays faster than four
+            # python-level device_put round-trips would.
+            _sent(c, "decode_operands", (tokens, lens, tbl, lens))
             pool = kv.pool_of(self.device)
             if eng.contract == "zoo":
                 # Stack each row's resident state (pad rows duplicate the
@@ -1405,47 +1513,47 @@ class _DecodeLane:
                 rows = [r.seq.state for r in batch]
                 state = None
                 if rows[0] is not None:
-                    rows = rows + [rows[-1]] * pad
-                    state = jax.tree_util.tree_map(
-                        lambda *xs: np.stack(xs), *rows)
-                with pool.lock:
+                    with span("paged.decode.state_in", step=step):
+                        rows = rows + [rows[-1]] * pad
+                        state = jax.tree_util.tree_map(
+                            lambda *xs: np.stack(xs), *rows)
+                    _sent(c, "state", state)
+                with pool.lock, span("paged.decode.step", step=step):
                     ks, vs = pool.arrays()
                     k2, v2, st2, logits = eng.decode_fn(
                         eng.weights[self.device.key], ks, vs, state, tokens,
                         lens, tbl, lens)
-                    logits = np.asarray(logits)  # sync before the slabs swap
+                    # sync before the slabs swap
+                    logits = _fetched(c, "logits", logits)
                     pool.set_arrays(k2, v2)
                 if st2 is not None:
-                    st2 = jax.tree_util.tree_map(np.asarray, st2)
-                nxt = np.empty(len(batch), np.int32)
-                for i, r in enumerate(batch):
+                    with span("paged.decode.state_out", step=step):
+                        st2 = _fetched(c, "state", st2)
+                        for i, r in enumerate(batch):
+                            r.seq.set_state(jax.tree_util.tree_map(
+                                lambda a, i=i: a[i], st2))
+                with span("paged.decode.sample", step=step):
                     # Position = tokens already emitted (prefill's token
                     # was position 0): identity-keyed, batch-independent.
-                    nxt[i] = sample_token(logits[i], r.sampling, r.rid,
-                                          len(r.out))
-                    if st2 is not None:
-                        r.seq.set_state(jax.tree_util.tree_map(
-                            lambda a, i=i: a[i], st2))
+                    nxt = [sample_token(logits[i], r.sampling, r.rid, len(r.out))
+                           for i, r in enumerate(batch)]
             else:
-                with pool.lock:
+                with pool.lock, span("paged.decode.step", step=step):
                     ks, vs = pool.arrays()
-                    # Host operands ride the call uncommitted: the
-                    # computation follows the committed slabs to this
-                    # lane's device, and the C++ dispatch path moves four
-                    # tiny arrays faster than four python-level
-                    # device_put round-trips would.
                     k2, v2, nxt = eng.decode_fn(ks, vs, tokens, lens, tbl, lens)
-                    nxt = np.asarray(nxt, np.int32)  # sync before the slabs swap
+                    # sync before the slabs swap
+                    nxt = np.asarray(_fetched(c, "logits", nxt), np.int32)
                     pool.set_arrays(k2, v2)
+            now = _now()
             for i, r in enumerate(batch):
                 kv.note_decoded(r.seq)
                 r.out.append(int(nxt[i]))
+                r.token_times.append(now)
                 if len(r.out) >= r.max_new:
                     done.append(r)
         finally:
             for s in held:
                 s._lock.release()
-        step_s = _now() - t0
         # Direct-route placement charge (the fix select_batch alone cannot
         # make): this step never touched a lane queue, so the recency
         # counter is the only signal least_loaded has that this device
@@ -1455,10 +1563,12 @@ class _DecodeLane:
         if callable(charge):
             charge(self.device, len(batch))
         with eng._m_lock:
-            eng._decode_steps += 1
             eng._decode_rows += len(batch)
             eng._decode_padded += pad
-            eng._token_lat.extend([step_s] * len(batch))
+        c.add("decode_steps")
+        c.add("output_tokens", len(batch))
+        c.add("decode_active_row_steps", active)
+        c.add("decode_left_out_row_steps", active - len(batch))
         with self._cv:
             for r in done:
                 self._active.remove(r)
