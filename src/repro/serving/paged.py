@@ -78,7 +78,8 @@ wired by ``PagedServeEngine.from_config(cfg)`` from the uniform
     stacked from each request's ``submit(..., extras=...)``.
 ``decode_fn(params, k_pages, v_pages, state, tokens, positions, tables, lengths)``
     ``-> (k_pages, v_pages, state, logits)`` — one ragged step over the
-    pools plus the batch's stacked resident state; ``logits`` ``(B, V)``
+    pools plus the batch's resident state, batch-leading (the engine
+    gathers it out of the state slab on the device); ``logits`` ``(B, V)``
     come back to the host for sampling.
 
 ``params`` is an executable argument, never a closure constant (a
@@ -88,10 +89,15 @@ pool device (``weights``, keyed by device key): a prefill runs on the
 device its request was placed on, with that device's replica, and each
 decode lane steps with its own device's replica.
 
-Resident state spills, migrates and ships with the sequence's pages
-(``SeqPages.set_state`` folds its bytes into the AGAS record — the §14
-memory-aware scheduler sees SSM state as honestly as KV pages), and
-sampling is host-side and bit-reproducible: token ``position`` of
+Resident state lives on the device next to the pages: each pool keeps a
+``StateSlab`` per row signature (the state's tree structure, leaf shapes
+and dtypes), and a sequence owns a slot in it.  Prefill scatters each row
+into its sequence's slot, and each decode step gathers its batch's rows
+out of the slab and scatters the stepped rows back, one jitted call each,
+so the state crosses to the host only when the sequence spills, migrates
+or ships (``SeqPages.set_state`` folds its bytes into the AGAS record —
+the §14 memory-aware scheduler sees SSM state as honestly as KV pages).
+Sampling is host-side and bit-reproducible: token ``position`` of
 request ``request_id`` draws from
 ``np.random.default_rng([seed, request_id, position])`` — a pure
 function of request identity, never of batch composition or fleet size
@@ -100,7 +106,8 @@ function of request identity, never of batch composition or fleet size
 Spans and counters (``repro.core.trace``) mark the engine's stage
 boundaries in a profiler trace (``paged.prefill.*``, ``paged.kv.*``,
 ``paged.decode.*``) and count its work: admissions and their wait, prompt
-and output tokens, decode steps and the rows that sat them out, spilled and
+and output tokens, decode steps and the rows that sat them out, the rows
+whose state came from a slab and the slabs that grew, spilled and
 refetched pages, and the bytes copied between host and device, by stage.
 ``PagedServeEngine.counters()`` returns the totals; docs/index.md,
 "Tracing the engine", says what each one means.
@@ -124,6 +131,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import agas
@@ -139,6 +147,7 @@ __all__ = [
     "PagedServeEngine",
     "SamplingParams",
     "SeqPages",
+    "StateSlab",
     "OutOfPages",
     "sample_token",
 ]
@@ -310,6 +319,141 @@ def _slab_gather(slab, idx):
     return slab[:, idx]
 
 
+# A state-slot index past every slab: a scatter row aimed at it writes
+# nothing (``mode="drop"``), which is how pad rows stay out of the slab.
+_NO_SLOT = np.iinfo(np.int32).max
+
+# Slot index vectors a slab keeps on its device before it drops them all.
+_SLOT_VECTORS = 256
+
+
+def _row_signature(rows):
+    """What one row of the batch-leading state tree ``rows`` is made of:
+    the tree's structure and each leaf's shape and dtype."""
+    leaves, treedef = jax.tree_util.tree_flatten(rows)
+    return treedef, tuple((tuple(a.shape[1:]), np.dtype(a.dtype).str) for a in leaves)
+
+
+@jax.jit
+def _state_rows(slab, slots):
+    return jax.tree_util.tree_map(lambda a: a[slots], slab)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _state_put(slab, slots, rows):
+    return jax.tree_util.tree_map(
+        lambda a, r: a.at[slots].set(r, mode="drop"), slab, rows)
+
+
+class StateSlab:
+    """One device's resident state for one row signature (zoo contract):
+    each leaf of the row with a leading axis of ``capacity`` slots, plus a
+    free list of slots.  A sequence owns a slot; the decode lane gathers
+    its batch's rows out of the slab and scatters the stepped rows back in
+    one jitted call each, so the state never leaves the device between
+    steps.  When the slots run out the slab doubles (``state_slab_grows``
+    in ``counters``).
+
+    ``take`` and ``put`` run under ``lock``, since ``put`` donates the slab.
+    ``warm`` compiles the gather and the scatter, with a step between them,
+    at the row counts a caller will use; a slab that grows warms them again
+    at its new size.  Slot index vectors are copied to the device once and
+    kept (a batch's slots change only when a sequence joins or leaves it,
+    and a copy up costs more host time than the dispatch it feeds); each
+    copy counts as ``h2d_bytes`` under ``state_slots``, host rows written in
+    under ``state``."""
+
+    def __init__(self, device, signature, capacity: int, counters: Counters):
+        self.device = device
+        self.signature = signature
+        self.counters = counters
+        self.row_bytes = sum(int(np.prod(shape)) * np.dtype(dt).itemsize
+                             for shape, dt in signature[1])
+        self.lock = threading.RLock()
+        self.capacity = 0
+        self.arrays = None
+        self.warm_rows: "tuple[int, ...]" = ()
+        self._free: "list[int]" = []
+        self._slot_vectors: "dict[bytes, jax.Array]" = {}
+        self._grow(max(1, int(capacity)))
+
+    def _on_device(self, slots) -> jax.Array:
+        """The slot index vector ``slots`` on the slab's device."""
+        idx = np.asarray(slots, np.int32)
+        key = idx.tobytes()
+        vec = self._slot_vectors.get(key)
+        if vec is None:
+            if len(self._slot_vectors) >= _SLOT_VECTORS:
+                self._slot_vectors.clear()
+            _sent(self.counters, "state_slots", idx)
+            vec = self._slot_vectors[key] = jax.device_put(idx, self.device.jax_device)
+        return vec
+
+    def _grow(self, capacity: int) -> None:
+        treedef, leaves = self.signature
+        dev = self.device.jax_device
+        fresh = [jnp.zeros((capacity - self.capacity, *shape), dt, device=dev)
+                 for shape, dt in leaves]
+        if self.arrays is not None:
+            fresh = [jnp.concatenate([a, f]) for a, f in
+                     zip(jax.tree_util.tree_leaves(self.arrays), fresh)]
+        self.arrays = jax.tree_util.tree_unflatten(treedef, fresh)
+        self._free = list(range(capacity - 1, self.capacity - 1, -1)) + self._free
+        self.capacity = capacity
+
+    def alloc(self) -> int:
+        with self.lock:
+            if not self._free:
+                self._grow(2 * self.capacity)
+                self.counters.add("state_slab_grows")
+                self.warm(self.warm_rows)
+            return self._free.pop()
+
+    def free(self, slot: int) -> None:
+        with self.lock:
+            if not 0 <= slot < self.capacity or slot in self._free:
+                raise ValueError(f"slot {slot} is not a taken slot of this slab")
+            self._free.append(slot)
+
+    @property
+    def num_free(self) -> int:
+        with self.lock:
+            return len(self._free)
+
+    def take(self, slots) -> Any:
+        """The rows at ``slots`` as one batch-leading tree on the device."""
+        with self.lock:
+            return _state_rows(self.arrays, self._on_device(slots))
+
+    def put(self, slots, rows) -> None:
+        """Scatter the batch-leading ``rows`` into ``slots``; a row aimed
+        at ``_NO_SLOT`` writes nothing.  Host rows are copied up first."""
+        if _row_signature(rows) != self.signature:
+            raise ValueError("rows do not match the slab's state signature")
+        dev = self.device.jax_device
+        if not all(isinstance(a, jax.Array) and a.devices() == {dev}
+                   for a in jax.tree_util.tree_leaves(rows)):
+            _sent(self.counters, "state", rows)
+            rows = jax.device_put(rows, dev)
+        with self.lock:
+            self.arrays = _state_put(self.arrays, self._on_device(slots), rows)
+
+    def warm(self, rows: "Sequence[int]", step: "Callable | None" = None) -> None:
+        """Compile the gather, ``step`` (batch-leading state in, stepped
+        state out) and the scatter at each row count of ``rows``, writing
+        nothing; the counts are warmed again if the slab grows."""
+        self.warm_rows = tuple(sorted(set(self.warm_rows) | set(rows)))
+        for n in rows:
+            with self.lock:
+                st = _state_rows(self.arrays, jax.device_put(
+                    np.zeros(n, np.int32), self.device.jax_device))
+            if step is not None:
+                st = step(st)
+            with self.lock:
+                self.arrays = _state_put(self.arrays, jax.device_put(
+                    np.full(n, _NO_SLOT, np.int32), self.device.jax_device), st)
+
+
 class PagePool:
     """Per-device page pool: two slab Buffers + a free list.
 
@@ -335,6 +479,21 @@ class PagePool:
             self._repin(b)
         self.lock = threading.RLock()
         self._free: "list[int]" = list(range(self.num_pages - 1, 0, -1))
+        # Resident state (zoo contract) next to the pages: one slab per
+        # row signature, made by its first row.
+        self.state_slabs: "dict[Any, StateSlab]" = {}
+
+    def state_slab(self, signature, capacity: int = 1) -> StateSlab:
+        """This device's slab for rows of ``signature``, made with
+        ``capacity`` slots when there is none yet."""
+        slab = self.state_slabs.get(signature)
+        if slab is None:
+            with self.lock:
+                slab = self.state_slabs.get(signature)
+                if slab is None:
+                    slab = self.state_slabs[signature] = StateSlab(
+                        self.device, signature, capacity, self.counters)
+        return slab
 
     @staticmethod
     def _repin(buf) -> None:
@@ -466,14 +625,16 @@ class SeqPages:
         self.seq_id = seq_id
         self.pages: "list[int]" = []
         self.length = 0
-        # Per-sequence resident state (zoo contract): an opaque pytree of
-        # host arrays — SSM recurrent state, conv windows, cross K/V —
-        # that rides with the pages through spill/migrate/export.  Its
-        # bytes fold into ``nbytes`` so the memory-aware scheduler and
-        # the LRU spiller see recurrent residency as honestly as KV.
-        self.state: Any = None
-        self._state_bytes = 0
-        self._spilled: "tuple[np.ndarray, np.ndarray] | None" = None
+        # Per-sequence resident state (zoo contract) — SSM recurrent state,
+        # conv windows, cross K/V — lives in a slot of the device's slab
+        # for its row signature and rides with the pages through
+        # spill/migrate/export.  Its bytes fold into ``nbytes`` so the
+        # memory-aware scheduler and the LRU spiller see recurrent
+        # residency as honestly as KV.
+        self._slab: "StateSlab | None" = None
+        self._slot: "int | None" = None
+        # Host copies (k, v, state row or None) while spilled.
+        self._spilled: "tuple | None" = None
         self._lock = threading.RLock()
         self._last_use = _now()
         dev = pool.device
@@ -492,25 +653,60 @@ class SeqPages:
         """Device-resident bytes: pages plus the recurrent state (which
         lives with the sequence — spilled sequences pin nothing)."""
         n = len(self.pages) * self.pool.spec.page_bytes
-        if self._spilled is None:
-            n += self._state_bytes
+        if self._slot is not None:
+            n += self._slab.row_bytes
         return n
 
     @property
     def spilled(self) -> bool:
         return self._spilled is not None
 
-    def set_state(self, state) -> None:
-        """Attach/replace the sequence's resident state (zoo contract)
-        and re-declare its bytes through AGAS — SSM/hybrid recurrent
-        state is real device pressure the §14 spill and memory-aware
-        placement must see, not a hidden side-car."""
+    @property
+    def state(self) -> Any:
+        """The resident state as one row: read out of the slot (device
+        arrays), or the host copy while spilled; None without state."""
         with self._lock:
-            self.state = state
-            self._state_bytes = sum(
-                int(a.nbytes) for a in jax.tree_util.tree_leaves(state)
-                if hasattr(a, "nbytes"))
-            self._account()
+            if self._slot is not None:
+                rows = self._slab.take([self._slot])
+                return jax.tree_util.tree_map(lambda a: a[0], rows)
+            return self._spilled[2] if self._spilled is not None else None
+
+    def set_state(self, state, row: "int | None" = None) -> None:
+        """Write the sequence's resident state (zoo contract) into its slot
+        on this device's slab for the row's signature.  ``state`` is one
+        row, or, with ``row``, a batch-leading tree (a prefill's output)
+        of which row ``row`` is this sequence's: the other rows write
+        nothing, and rows already on the device stay there.  A slot is
+        taken, and the bytes re-declared through AGAS — SSM/hybrid
+        recurrent state is real device pressure the §14 spill and
+        memory-aware placement must see — only when the signature
+        changes.  ``None`` drops the state."""
+        with self._lock:
+            if self._spilled is not None:
+                raise RuntimeError(
+                    f"sequence #{self.seq_id} is spilled: refetch it first")
+            if state is None:
+                self._drop_slot()
+                self._account()
+                return
+            if row is None:
+                state = jax.tree_util.tree_map(
+                    lambda a: a[None] if isinstance(a, jax.Array)
+                    else np.asarray(a)[None], state)
+            slab = self.pool.state_slab(_row_signature(state))
+            if self._slab is not slab:
+                self._drop_slot()
+                self._slab, self._slot = slab, slab.alloc()
+                self._account()
+            slots = np.full(jax.tree_util.tree_leaves(state)[0].shape[0],
+                            _NO_SLOT, np.int32)
+            slots[row or 0] = self._slot
+            slab.put(slots, state)
+
+    def _drop_slot(self) -> None:
+        if self._slot is not None:
+            self._slab.free(self._slot)
+        self._slab = self._slot = None
 
     def _account(self) -> None:
         try:
@@ -521,18 +717,24 @@ class SeqPages:
     # -- spill / refetch (scheduler-driven, DESIGN.md §14) -------------------
 
     def spill(self) -> Future:
-        """Evict to host RAM (future of True when pages were released):
-        page contents copy out, the pages return to the pool's free list,
-        and the AGAS record moves to ``HOST_KEY`` — device page pressure
-        drops immediately, exactly like ``Buffer.spill``."""
+        """Evict to host RAM (future of True when pages or state were
+        released): page contents and the state row copy out, the pages
+        return to the pool's free list and the slot to its slab, and the
+        AGAS record moves to ``HOST_KEY`` — device pressure drops
+        immediately, exactly like ``Buffer.spill``."""
         return self.pool.device.ops_queue.submit(self._spill_now)
 
     def _spill_now(self) -> bool:
         with self._lock:
-            if self._spilled is not None or not self.pages:
+            if self._spilled is not None or not (self.pages or self._slot is not None):
                 return False
             with span("paged.kv.spill", seq=self.seq_id):
-                self._spilled = self.pool.read_pages(self.pages, stage="spill")
+                k, v = self.pool.read_pages(self.pages, stage="spill")
+                state = None
+                if self._slot is not None:
+                    state = _fetched(self.pool.counters, "state", self.state)
+                    self._drop_slot()
+                self._spilled = (k, v, state)
             self.pool.counters.add("spilled_pages", len(self.pages))
             self.pool.free(self.pages)
             self.pages = []
@@ -544,19 +746,21 @@ class SeqPages:
             return True
 
     def ensure_resident(self) -> None:
-        """Refetch after a spill: re-allocate (page ids may differ — the
-        handle is the identity, not the page numbers) and write the host
-        copy back."""
+        """Refetch after a spill: re-allocate (page ids and the state slot
+        may differ — the handle is the identity, not the page numbers) and
+        write the host copies back."""
         with self._lock:
             if self._spilled is None:
                 return
-            k, v = self._spilled
+            k, v, state = self._spilled
             pages = self.pool.alloc(len(k))
             with span("paged.kv.refetch", seq=self.seq_id):
                 self.pool.write_pages(pages, k, v, stage="refetch")
+                self._spilled = None
+                if state is not None:
+                    self.set_state(state)
             self.pool.counters.add("refetched_pages", len(pages))
             self.pages = pages
-            self._spilled = None
             dev = self.pool.device
             agas.registry.update_placement(
                 self.gid, agas.Placement(dev.key, dev.jax_device.process_index))
@@ -664,8 +868,7 @@ class PagedKVCache:
                 seq.pool.free(seq.pages)
             seq.pages = []
             seq._spilled = None
-            seq.state = None
-            seq._state_bytes = 0
+            seq._drop_slot()
             seq.length = 0
             if seq._finalizer is not None:
                 seq._finalizer.detach()
@@ -692,6 +895,32 @@ class PagedKVCache:
             tbl[i, :n] = s.pages
             lens[i] = s.length
         return tbl, lens
+
+    @staticmethod
+    def _slab_of(seqs: "Sequence[SeqPages]") -> "StateSlab | None":
+        slab = seqs[0]._slab
+        if any(s._slab is not slab for s in seqs):
+            raise ValueError("a batch's sequences must share one state slab")
+        return slab
+
+    def state_rows(self, seqs: "Sequence[SeqPages]", rows: int):
+        """The resident state of ``seqs`` as one ``(rows, ...)`` tree,
+        gathered on the device out of their slab in one call (rows past
+        ``len(seqs)`` repeat the last), or None when they hold none."""
+        slab = self._slab_of(seqs)
+        if slab is None:
+            return None
+        slots = [s._slot for s in seqs]
+        return slab.take(slots + slots[-1:] * (rows - len(slots)))
+
+    def put_state_rows(self, seqs: "Sequence[SeqPages]", state) -> None:
+        """Scatter a stepped ``(rows, ...)`` state tree back into the slots
+        of ``seqs`` in one call on the device; rows past ``len(seqs)`` (pad
+        rows) write nothing."""
+        slots = np.full(jax.tree_util.tree_leaves(state)[0].shape[0],
+                        _NO_SLOT, np.int32)
+        slots[:len(seqs)] = [s._slot for s in seqs]
+        self._slab_of(seqs).put(slots, state)
 
     # -- maintenance ---------------------------------------------------------
 
@@ -751,8 +980,9 @@ class PagedKVCache:
         """Re-home a sequence: ALL its pages leave the source slabs as one
         stacked read and land in the target pool as one stacked write —
         the §10 lesson (batch the percolation, never per-page transfers)
-        applied to rebalancing.  The AGAS record moves with the pages, so
-        affinity immediately scores the new home."""
+        applied to rebalancing.  The state row moves into the target
+        device's slab.  The AGAS record moves with the pages, so affinity
+        immediately scores the new home."""
         dst = self.pool_of(device)
         with seq._lock:
             if seq.pool is dst:
@@ -763,9 +993,15 @@ class PagedKVCache:
                 k, v = src.read_pages(seq.pages, stage="migrate")
                 pages = dst.alloc(len(seq.pages))
                 dst.write_pages(pages, k, v, stage="migrate")
+            state = None
+            if seq._slot is not None:
+                state = _fetched(self.counters, "state", seq.state)
+                seq._drop_slot()
             src.free(seq.pages)
             seq.pool = dst
             seq.pages = pages
+            if state is not None:
+                seq.set_state(state)
             agas.registry.update_placement(
                 seq.gid, agas.Placement(device.key, device.jax_device.process_index))
             seq._account()
@@ -783,7 +1019,7 @@ class PagedKVCache:
         with seq._lock:
             seq.ensure_resident()
             k, v = seq.pool.read_pages(seq.pages, stage="export")
-            state = _fetched(self.counters, "export", seq.state)
+            state = _fetched(self.counters, "state", seq.state)
             return {"k": k, "v": v, "length": int(seq.length), "state": state}
 
     def import_seq(self, device, payload: dict) -> SeqPages:
@@ -889,6 +1125,10 @@ class PagedServeEngine:
         self.weights = weights
         self.prefill_fn = prefill_fn
         self.decode_fn = decode_fn
+        # The step as built, which ``_warm_state_path`` compiles: a wrapper
+        # set over ``decode_fn`` later (one that counts served steps) sees
+        # served steps alone.
+        self._built_decode_fn = decode_fn
         self.contract = contract
         self._next_rid = 0
         # Optional row-count palette preseeded into every decode lane's
@@ -1044,9 +1284,10 @@ class PagedServeEngine:
         docs/index.md, "Tracing the engine"): ``admitted``,
         ``admission_wait_s``, ``prefill_tokens``, ``output_tokens``,
         ``decode_steps``, ``decode_active_row_steps``,
-        ``decode_left_out_row_steps``, ``spilled_pages``,
-        ``refetched_pages``, and ``h2d_bytes`` / ``d2h_bytes`` keyed by
-        stage.  A total appears once something has been counted in it."""
+        ``decode_left_out_row_steps``, ``state_rows_on_device``,
+        ``state_slab_grows``, ``spilled_pages``, ``refetched_pages``, and
+        ``h2d_bytes`` / ``d2h_bytes`` keyed by stage.  A total appears
+        once something has been counted in it."""
         return self._counters.snapshot()
 
     def __enter__(self) -> "PagedServeEngine":
@@ -1170,12 +1411,11 @@ class PagedServeEngine:
         with span("paged.prefill.step", rid=rid):
             if self.contract == "zoo":
                 # Committed inputs put the computation on ``home``, next to
-                # that device's weights replica.
+                # that device's weights replica.  The state stays there.
                 home = homes[0]  # one device per zoo call (see _run_prefill)
                 on_home = jax.device_put((batch, extras), home.jax_device)
                 k, v, state, logits = self.prefill_fn(self.weights[home.key], *on_home)
                 logits = _fetched(c, "logits", logits)
-                state = _fetched(c, "state", state)
             else:
                 k, v, nxt = self.prefill_fn(batch)
                 nxt = np.asarray(_fetched(c, "logits", nxt), np.int32)
@@ -1200,8 +1440,8 @@ class PagedServeEngine:
             # k[i]: (L, T', Kh, D) — the whole prompt pages in as one write.
             self.kv.append(req.seq, k[i], v[i])
             if state is not None:
-                req.seq.set_state(
-                    jax.tree_util.tree_map(lambda a, i=i: a[i], state))
+                self._warm_state_path(pool, state)
+                req.seq.set_state(state, row=i)
             req.out.append(int(nxt[i]))
             req.token_times.append(_now())
             c.add("output_tokens", 1)
@@ -1210,6 +1450,34 @@ class PagedServeEngine:
             else:
                 self._lane_for(pool.device).admit(req)
             self._prefill_done(req)
+
+    def _warm_state_path(self, pool: PagePool, state) -> None:
+        """Make ``pool``'s slab for rows like those of ``state`` (a
+        prefill's batch-leading output) on first use, one slot per row of
+        the decode cap, and compile the decode lane's path over it at
+        every row count of ``decode_shapes``: the gather, the decode step
+        and the scatter.  A device tree in the state's place compiles a decode
+        executable apart from one warmed with host stacks, so this lands
+        with the first stateful prefill rather than in a decode step."""
+        cap = self.decode_policy.max_batch
+        slab = pool.state_slab(_row_signature(state),
+                               capacity=cap if cap is not None else 64)
+        if slab.warm_rows or not self.decode_shapes:
+            return
+        weights = self.weights[pool.device.key]
+
+        def step(st):
+            n = jax.tree_util.tree_leaves(st)[0].shape[0]
+            tbl = np.zeros((n, self.max_pages), np.int32)
+            lens = np.zeros((n,), np.int32)
+            with pool.lock:
+                ks, vs = pool.arrays()
+                k2, v2, st2, _ = self._built_decode_fn(
+                    weights, ks, vs, st, np.ones((n,), np.int32), lens, tbl, lens)
+                pool.set_arrays(k2, v2)
+            return st2
+
+        slab.warm(self.decode_shapes, step)
 
     def _prefill_done(self, req: "_PagedRequest") -> None:
         """Prefill is done with this request (admitted or settled): mark
@@ -1508,16 +1776,14 @@ class _DecodeLane:
             _sent(c, "decode_operands", (tokens, lens, tbl, lens))
             pool = kv.pool_of(self.device)
             if eng.contract == "zoo":
-                # Stack each row's resident state (pad rows duplicate the
-                # last row, discarded on the way back out).
-                rows = [r.seq.state for r in batch]
+                # Gather the rows' resident state out of their slab on the
+                # device (pad rows repeat the last row, and write nothing
+                # on the way back in).
                 state = None
-                if rows[0] is not None:
+                if seqs[0]._slot is not None:
                     with span("paged.decode.state_in", step=step):
-                        rows = rows + [rows[-1]] * pad
-                        state = jax.tree_util.tree_map(
-                            lambda *xs: np.stack(xs), *rows)
-                    _sent(c, "state", state)
+                        state = kv.state_rows(seqs, want)
+                    c.add("state_rows_on_device", b_real)
                 with pool.lock, span("paged.decode.step", step=step):
                     ks, vs = pool.arrays()
                     k2, v2, st2, logits = eng.decode_fn(
@@ -1528,10 +1794,7 @@ class _DecodeLane:
                     pool.set_arrays(k2, v2)
                 if st2 is not None:
                     with span("paged.decode.state_out", step=step):
-                        st2 = _fetched(c, "state", st2)
-                        for i, r in enumerate(batch):
-                            r.seq.set_state(jax.tree_util.tree_map(
-                                lambda a, i=i: a[i], st2))
+                        kv.put_state_rows(seqs, st2)
                 with span("paged.decode.sample", step=step):
                     # Position = tokens already emitted (prefill's token
                     # was position 0): identity-keyed, batch-independent.
@@ -1702,10 +1965,7 @@ def paged_worker_decode(payload: dict) -> np.ndarray:
             kv.ensure_slot(seq)
             tbl, lens = kv.table([seq], max_pages)
             tokens = np.asarray([out[-1]], np.int32)
-            state = None
-            if seq.state is not None:
-                state = jax.tree_util.tree_map(
-                    lambda a: np.asarray(a)[None], seq.state)
+            state = kv.state_rows([seq], 1)
             with pool.lock:
                 ks, vs = pool.arrays()
                 k2, v2, st2, logits = ctx["dec"](
@@ -1713,8 +1973,7 @@ def paged_worker_decode(payload: dict) -> np.ndarray:
                 logits = np.asarray(logits)
                 pool.set_arrays(k2, v2)
             if st2 is not None:
-                seq.set_state(jax.tree_util.tree_map(
-                    lambda a: np.asarray(a)[0], st2))
+                kv.put_state_rows([seq], st2)
             kv.note_decoded(seq)
             out.append(sample_token(logits[0], sp, rid, len(out)))
     finally:

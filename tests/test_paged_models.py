@@ -322,6 +322,137 @@ def test_export_import_roundtrip_preserves_state(device):
 
 
 # ---------------------------------------------------------------------------
+# resident state slabs: each row lives in its sequence's slot on the device
+# ---------------------------------------------------------------------------
+
+
+def _random_row(rng, signature):
+    treedef, leaves = signature
+    return jax.tree_util.tree_unflatten(
+        treedef, [rng.normal(size=shape).astype(dt) for shape, dt in leaves])
+
+
+def _assert_same_row(got, want):
+    jax.tree_util.tree_map(
+        lambda g, w: np.testing.assert_array_equal(np.asarray(g), w), got, want)
+
+
+def _state_round_trip(case, devices):
+    """Write a state row, put its sequence through ``case``, and read the
+    row back bit-identical (runs in a child with two devices for
+    ``migrate``)."""
+    spec = PageSpec(layers=1, page_size=4, kv_heads=1, head_dim=2)
+    kv = PagedKVCache(spec, devices=devices, pool_pages=16)
+    rng = np.random.default_rng(5)
+    seq = kv.new_seq(devices[0])
+    k = rng.normal(size=(1, 6, 1, 2)).astype(np.float32)
+    kv.append(seq, k, -k)
+    row = {"s": rng.normal(size=(3, 5)).astype(np.float32),
+           "c": rng.normal(size=(2,)).astype(np.float32)}
+    seq.set_state(row)
+    slab = next(iter(seq.pool.state_slabs.values()))
+    back = seq
+    if case == "spill_refetch":
+        assert seq.spill().get()
+        assert slab.num_free == slab.capacity  # a spilled sequence pins no slot
+        seq.ensure_resident()
+    elif case == "migrate":
+        kv.migrate(seq, devices[1])
+        assert slab.num_free == slab.capacity
+        assert seq.pool.device.key == devices[1].key
+        assert [s.device.key for s in seq.pool.state_slabs.values()] == [devices[1].key]
+    elif case == "export_import":
+        back = kv.import_seq(devices[0], kv.export_seq(seq))
+    elif case == "free_reuse":
+        kv.free_seq(seq)
+        assert kv.new_seq(devices[0]).state is None
+        back = kv.new_seq(devices[0])
+        row = {"s": rng.normal(size=(3, 5)).astype(np.float32),
+               "c": rng.normal(size=(2,)).astype(np.float32)}
+        back.set_state(row)
+        assert slab.num_free == slab.capacity - 1  # the freed slot, taken again
+    _assert_same_row(back.state, row)
+    assert back.nbytes == len(back.pages) * spec.page_bytes + (15 + 2) * 4
+
+
+_MIGRATE_CHILD = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
+                               + os.environ.get("XLA_FLAGS", ""))
+    from repro.core import get_all_devices
+    from tests.test_paged_models import _state_round_trip
+
+    _state_round_trip("migrate", list(get_all_devices().get()))
+    print("MIGRATE_OK")
+""")
+
+
+@pytest.mark.parametrize("case", ["spill_refetch", "migrate", "export_import", "free_reuse"])
+def test_resident_state_reads_back_bit_identical(case, device):
+    if case != "migrate":
+        _state_round_trip(case, [device])
+        return
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
+    r = subprocess.run([sys.executable, "-c", _MIGRATE_CHILD], capture_output=True,
+                       text=True, timeout=300, env=env, cwd=root)
+    assert r.returncode == 0, r.stdout + r.stderr[-3000:]
+    assert "MIGRATE_OK" in r.stdout
+
+
+def test_pad_rows_leave_every_other_slot_unchanged(device):
+    """Decode steps of one real row padded to two (``decode_shapes=(2,)``)
+    write that row's slot alone: rows parked in the other slots read back
+    bit-identical, and the tokens are the model's own."""
+    cfg, params = _setup("mamba2-130m")
+    rng = np.random.default_rng(8)
+    prompt = _prompts(cfg, rng)[0]
+    want = _oracle_tokens(cfg, params, prompt, None, MAX_NEW)
+    eng = PagedServeEngine.from_config(
+        cfg, params=params, devices=[device], max_seq_len=MAX_SEQ,
+        decode_shapes=(2,), name="t-state-pad")
+    try:
+        eng.submit(prompt, 2).get(timeout=600)  # makes the slab
+        (slab,) = eng.kv.pool_of(device).state_slabs.values()
+        parked = []
+        for _ in range(3):
+            seq = eng.kv.new_seq(device)
+            row = _random_row(rng, slab.signature)
+            seq.set_state(row)
+            parked.append((seq, row))
+        got = list(np.asarray(eng.submit(prompt, MAX_NEW).get(timeout=600)))
+        c = eng.counters()
+    finally:
+        eng.close()
+    assert got == want
+    for seq, row in parked:
+        _assert_same_row(seq.state, row)
+    assert c["state_rows_on_device"] == 1 + (MAX_NEW - 1)
+
+
+def test_state_slab_doubles_without_losing_a_row(device):
+    spec = PageSpec(layers=1, page_size=4, kv_heads=1, head_dim=2)
+    kv = PagedKVCache(spec, devices=[device], pool_pages=16)
+    rng = np.random.default_rng(9)
+    rows = []
+    for _ in range(5):  # a slab made by its first row holds one slot
+        seq = kv.new_seq(device)
+        row = {"s": rng.normal(size=(4, 3)).astype(np.float32)}
+        seq.set_state(row)
+        rows.append((seq, row))
+    (slab,) = kv.pool_of(device).state_slabs.values()
+    assert slab.capacity == 8 and slab.num_free == 3
+    assert kv.counters.snapshot()["state_slab_grows"] == 3  # 1 -> 2 -> 4 -> 8
+    for seq, row in rows:
+        _assert_same_row(seq.state, row)
+    for seq, _ in rows:
+        kv.free_seq(seq)
+    assert slab.num_free == slab.capacity
+
+
+# ---------------------------------------------------------------------------
 # cross-locality: prefill here, ship pages, decode THERE, same tokens
 # ---------------------------------------------------------------------------
 

@@ -50,7 +50,9 @@ def _page_write_bytes(spec, pages):
 def test_host_device_bytes_by_stage_match_the_shapes(arch, device):
     """One request of a 40-token prompt and 4 output tokens: its prefill,
     its 3-page prompt write (padded to 4), then 3 decode steps of 2 rows,
-    one of them a pad row (``decode_shapes=(2,)``)."""
+    one of them a pad row (``decode_shapes=(2,)``).  Resident state stays
+    on the device: only the slot index vectors of its prefill write and of
+    the steps' gather and scatter are copied, each once, never the state."""
     T, new = 40, 4
     cfg, params, eng = _engine(arch, device)
     try:
@@ -66,19 +68,23 @@ def test_host_device_bytes_by_stage_match_the_shapes(arch, device):
     row_logits = int(np.prod(logits.shape)) * logits.dtype.itemsize
     row_state = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                     for a in jax.tree_util.tree_leaves(state))
-    steps, rows = new - 1, 2
+    steps, rows, real = new - 1, 2, 1
     operands = rows * 4 + 2 * rows * 4 + rows * eng.max_pages * 4  # tokens, lengths x2, tables
     want_h2d = {"prefill_tokens": T * 4,
                 "prompt_kv": _page_write_bytes(spec, spec.pages_for(T)),
                 "decode_operands": steps * operands}
     want_d2h = {"logits": row_logits * (1 + steps * rows), "prompt_kv": kv_bytes}
     if row_state:
-        want_h2d["state"] = steps * rows * row_state
-        want_d2h["state"] = row_state + steps * rows * row_state
+        # the prefill's one-row write, then the steps' gather and scatter
+        want_h2d["state_slots"] = 4 + 2 * rows * 4
     assert c["h2d_bytes"] == want_h2d
     assert c["d2h_bytes"] == want_d2h
     assert c["decode_steps"] == steps
     assert c["prefill_tokens"] == T
+    assert c.get("state_rows_on_device", 0) == (steps * real if row_state else 0)
+    assert "state_slab_grows" not in c
+    # a stateless model makes no slab
+    assert bool(next(iter(eng.kv.pools.values())).state_slabs) == bool(row_state)
 
 
 def test_spill_and_refetch_count_pages_and_bytes(device):
