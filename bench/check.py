@@ -48,14 +48,23 @@ def within(numbers: dict) -> bool:
     return all(v["value"] <= v["limit"] for v in numbers.values())
 
 
-def sample(finished, *, seed: int, min_tokens: int, max_requests: int):
+def sample(finished, *, seed: int, min_tokens: int, max_requests: int, homes):
     """The longest finished request, then others drawn from the seed until
-    the sample serves ``min_tokens`` tokens or holds ``max_requests``."""
+    the sample serves ``min_tokens`` tokens or holds ``max_requests``.
+    ``homes`` gives the device each request was served on: the first drawn
+    of each device that the sample lacks goes in before the rest, so that
+    every weights replica and decode lane is checked."""
     if not finished:
         return []
     order = sorted(range(len(finished)), key=lambda i: -(finished[i][0].size + finished[i][1].size))
     picked = [order[0]]
     rest = [order[i] for i in rng_for(seed, 2).permutation(len(order) - 1) + 1]
+    seen = {homes[order[0]]}
+    for i in rest:
+        if homes[i] not in seen and len(picked) < max_requests:
+            seen.add(homes[i])
+            picked.append(i)
+    rest = [i for i in rest if i not in picked]
     for i in rest:
         if len(picked) >= max_requests or sum(finished[j][1].size for j in picked) >= min_tokens:
             break

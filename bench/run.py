@@ -10,10 +10,12 @@ The cell is an entry of ``BENCHMARK.json`` ``workloads``: a configuration
    compilation cache (``JAX_COMPILATION_CACHE_DIR``, else ``.jax_cache``);
 2. fails, printing no result, unless JAX finds a TPU whose ``device_kind``
    is in ``bench/peaks.json``, with as many chips as the cell asks for;
-3. makes the weights on the device from the seed and builds
-   ``PagedServeEngine.from_config`` with the configuration's engine settings;
-4. warms every prefill and decode shape the traffic reaches, then runs a
-   lead-in of the cell's own traffic (all of this is ``setup_s``);
+3. makes the weights from the seed on the first of the cell's chips and
+   builds ``PagedServeEngine.from_config`` over all of them (one weights
+   replica, page pool and decode lane on each, requests placed round robin)
+   with the configuration's engine settings;
+4. warms every prefill and decode shape the traffic reaches on every chip,
+   then runs a lead-in of the cell's own traffic (all of this is ``setup_s``);
 5. measures for ``--seconds``: an open loop submits each request at its due
    time; with ``--trace 1`` the profiler records the first
    ``TRACE_SECONDS`` of the window;
@@ -41,6 +43,7 @@ import json  # noqa: E402
 import math  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+from collections import Counter  # noqa: E402
 from dataclasses import dataclass  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -110,19 +113,19 @@ class RunData:
     cfg: dict
     workload: dict
     trace: object            # bench.xplane.Trace, or None
-    decodes: list            # Probe.decodes: (padded rows, first pages, lengths)
+    decodes: list            # Probe.decodes: (padded rows, first pages, lengths, device)
     prefills: list           # Probe prefill prompt lengths, by call index
-    samples: list            # (time, in flight, used pages) within the window
-    pool_pages: int
+    samples: list            # (time, in flight, used pages of each pool) within the window
+    pool_pages: int          # pages of one pool
     peak: dict
     work: object
     itemsize: int
 
 
-def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, device,
+def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, devices,
              peak: dict, rate: "float | None" = None,
              control: bool = False, fault=None, t_start: float = T_START) -> dict:
-    """One run of one cell on ``device``; returns the result's parts."""
+    """One run of one cell on ``devices`` (JAX devices); returns the result's parts."""
     import jax
     import numpy as np
 
@@ -131,8 +134,8 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
 
     clock = CompileClock()
     key = jax.random.PRNGKey(int(loadgen.rng_for(seed, 0).integers(2**31 - 1)))
-    weights = serve.make_weights(cfg, key, device)
-    eng = serve.build(cfg, device, weights, name=wl["name"])
+    weights = serve.make_weights(cfg, key, devices[0])
+    eng = serve.build(cfg, devices, weights, name=wl["name"])
     if fault is not None:  # tests: break the timed path underneath the probe
         fault(eng)
     probe = serve.Probe()
@@ -140,16 +143,16 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
     e = cfg["engine"]
     reqs = loadgen.schedule(traffic, seed=seed, seconds=seconds, vocab=cfg["model"]["vocab_size"],
                             max_seq_len=e["max_seq_len"], rate=rate)
-    serve.warm_up(eng, traffic["prompt_tokens"]["palette"])
+    serve.warm_up(eng, probe, traffic["prompt_tokens"]["palette"])
     probe.clear()
-    pool = next(iter(eng.kv.pools.values()))
+    pools = list(eng.kv.pools.values())
     t_warm = time.perf_counter()
 
     origin = time.perf_counter() + 0.05
     loop = serve.OpenLoop(eng, reqs, origin)
     ws = origin + float(traffic["lead_in_s"])
     we = ws + float(seconds)
-    loop.run_until(ws, pool)
+    loop.run_until(ws, pools)
     compiles_before = clock.count
     setup_s = ws - t_start
     t_trace = None
@@ -162,21 +165,27 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
         window_span = jax.profiler.TraceAnnotation(tr.WINDOW_SPAN)
         window_span.__enter__()
         t_trace = time.perf_counter()
-        loop.run_until(min(we, t_trace + TRACE_SECONDS), pool)
+        loop.run_until(min(we, t_trace + TRACE_SECONDS), pools)
         window_span.__exit__(None, None, None)
         jax.profiler.stop_trace()
         decodes, prefills = list(probe.decodes), [p[0] if p else None for p in probe.prefills]
-    loop.run_until(we, pool)
+    loop.run_until(we, pools)
     compiles = clock.count - compiles_before
     in_window = [s for s in loop.sent if ws <= s.due < we]
     deadline = we + float(traffic["drain_limit_s"])
     loop.wait(in_window, deadline)
     t_drained = time.perf_counter()
-    stats = device.memory_stats() or {}
-    memory_peak = int(stats.get("peak_bytes_in_use", 0))
+    memory_peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devices)
 
-    # Results of the requests due in the window.
-    lat, norm, failed, finished = [], [], 0, []
+    # Results of the requests due in the window, with the device each was
+    # prefilled on.
+    prefill_logits, home = {}, {}
+    for p in probe.prefills:
+        if p is not None:
+            key = np.asarray(p[1])[0].tobytes()
+            prefill_logits[key] = np.asarray(p[2])[0]
+            home[key] = p[3]
+    lat, norm, failed, finished, homes = [], [], 0, [], []
     for s in in_window:
         out = None
         if s.future is not None and s.future.done():
@@ -192,6 +201,7 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
         lat.append(s.done - s.due)
         norm.append((s.done - s.due) / out.size * 1e3)
         finished.append((s.req.prompt, out))
+        homes.append(home.get(s.req.prompt.tobytes()))
     tokens_completed = sum(s.req.max_new for s in loop.sent[:loop.next]
                            if s.done is not None and ws <= s.done < we)
     # Every output token the steps launched inside the window produce: the
@@ -200,25 +210,25 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
     late = sorted(s.sent - s.due for s in loop.sent[:loop.next])
     window_samples = [x for x in loop.samples if ws <= x[0] < we]
     backlog = [x[1] for x in window_samples]
-    prefill_logits = {}
-    for p in probe.prefills:
-        if p is not None:
-            prefill_logits[np.asarray(p[1])[0].tobytes()] = np.asarray(p[2])[0]
-    pool_pages = pool.num_pages - 1
+    pool_pages = pools[0].num_pages - 1
+    pages_peak = [max((x[2][i] for x in window_samples), default=0) for i in range(len(pools))]
+    # Steps since warm-up on each device: how the placement spread the work.
+    steps_by_device = {kind: dict(Counter(r[3] for r in record if r is not None))
+                       for kind, record in (("prefill", probe.prefills), ("decode", probe.decodes))}
     stored_off = sorted((probe.dtypes | {str(pl.k_slab.array().dtype) for pl in eng.kv.pools.values()})
                         - {cfg["dtype"]})
     eng.close()
     for pl in eng.kv.pools.values():
         for slab in (pl.k_slab, pl.v_slab):
             slab.array().delete()
-    del eng, pool
+    del eng, pools
     gc.collect()
 
     # Correctness: a seeded sample against the plain reference.
     ref = check.Reference(cfg, serve.reference_module(cfg), weights, e["max_seq_len"])
     c = cfg["check"]
     picked = check.sample(finished, seed=seed, min_tokens=c["sample_min_tokens"],
-                          max_requests=c["sample_max_requests"])
+                          max_requests=c["sample_max_requests"], homes=homes)
     got = check.compare(ref, picked, prefill_logits)
     got["stored_dtype_off"] = len(stored_off)
     numbers = check.decide(got, c["limits"])
@@ -245,8 +255,10 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
             "backlog_start": backlog[0] if backlog else None,
             "backlog_end": backlog[-1] if backlog else None,
             "backlog_max": max(backlog) if backlog else None,
+            "backlog_tenths": [backlog[i * (len(backlog) - 1) // 10] for i in range(11)] if backlog else None,
+            "offered_tokens_in_window": sum(s.req.max_new for s in in_window),
             "drain_s": t_drained - we, "check_s": t_checked - t_drained,
-            "pages_peak": max((x[2] for x in window_samples), default=0), "pool_pages": pool_pages,
+            "pages_peak": pages_peak, "pool_pages": pool_pages, "steps_by_device": steps_by_device,
             "sample_requests": got["requests"], "sample_tokens": got["tokens"],
             "stored_dtypes_off": stored_off,
         },
@@ -324,7 +336,7 @@ def main(argv=None) -> int:
               f"{len(jd)} {jd[0].platform} device(s) of kind {jd[0].device_kind!r}", file=sys.stderr)
         return 1
     res = run_cell(spec, wl, cfg, traffic, seed=args.seed, seconds=args.seconds,
-                   trace=bool(args.trace), device=jd[0], peak=peaks[jd[0].device_kind],
+                   trace=bool(args.trace), devices=jd[:wl["chips"]], peak=peaks[jd[0].device_kind],
                    rate=args.rate, control=bool(args.control))
     print("BENCH stats " + json.dumps(res["stats"]), flush=True)
     if args.control:

@@ -4,10 +4,20 @@
 
 Each rate is a ``run.py --rate`` run with the cell's own arrival process and
 lengths.  Prints, per rate, the backlog (requests submitted and not yet
-finished) at the window's start and end and its peak, how long the requests
-due in the window took to drain after it closed, the failures, and the
-end-to-end metrics.  The knee is the highest rate whose backlog does not
-grow over the window and at which no request fails.  This process never
+finished) at the window's start and end, its peak and its value at each
+tenth of the window, how long the requests due in the window took to drain
+after it closed, the failures, the share of the output tokens that the
+window's requests ask for that the window made (``made_share``), and the
+end-to-end metrics.
+
+The knee is the highest rate at which no request fails and ``made_share``
+is at least ``MADE_SHARE``.  A rate that the system sustains makes a little
+under all of its offer: the tokens of the requests due near the window's
+close come after it, and a lead-in shorter than a request's latency
+carries fewer into it.  A system a twentieth short of the rate makes about
+a twentieth less again.  The backlog is printed, not judged: where a
+request takes longer than the lead-in, it is still filling as the window
+opens, so it grows over the window at any rate.  This process never
 touches JAX, so each child has the chip to itself.
 """
 from __future__ import annotations
@@ -19,6 +29,7 @@ import sys
 from pathlib import Path
 
 RUN = Path(__file__).resolve().parent / "run.py"
+MADE_SHARE = 0.9
 
 
 def child(workload: str, seed: int, seconds: float, *extra: str) -> dict:
@@ -46,10 +57,14 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
     cols = ("rate", "due_in_window", "failed", "backlog_start", "backlog_end", "backlog_max",
-            "drain_s", "output_tokens_per_s", "latency_p90_s", "norm_latency_p90_ms", "correct")
+            "drain_s", "output_tokens_per_s", "made_share", "latency_p90_s", "norm_latency_p90_ms",
+            "correct", "holds")
     print("SWEEP " + " | ".join(cols), flush=True)
     for rate in (float(r) for r in args.rates.split(",")):
         row = {"rate": rate, **child(args.workload, args.seed, args.seconds, "--rate", str(rate))}
+        if row.get("offered_tokens_in_window"):
+            row["made_share"] = row["tokens_in_window"] / row["offered_tokens_in_window"]
+            row["holds"] = row["failed"] == 0 and row["made_share"] >= MADE_SHARE
         print("SWEEP " + " | ".join(str(row.get(c)) for c in cols), flush=True)
         print("SWEEPROW " + json.dumps(row), flush=True)
     return 0

@@ -1,11 +1,14 @@
 """``python -m pytest bench/tests`` from the checkout's root: the benchmark
-and the system under test (``src/``) on the path, JAX on the CPU."""
+and the system under test (``src/``) on the path, JAX on the CPU with two
+devices, so that a cell can run over more than one."""
 import os
 import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2").strip()
 for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))

@@ -60,10 +60,11 @@ def control_size(config, traffic_mix):
     return spec, wl, cfg, traffic
 
 
-def _run(cell, fault=None, control=False, seed=2**31 + 7, size=tiny):
+def _run(cell, fault=None, control=False, seed=2**31 + 7, size=tiny, chips=1):
     spec, wl, cfg, traffic = size(*cell)
+    wl["chips"] = chips
     return run.run_cell(spec, wl, cfg, traffic, seed=seed, seconds=2.0, trace=False,
-                        device=jax.devices()[0], peak=PEAK, fault=fault, control=control)
+                        devices=jax.devices()[:chips], peak=PEAK, fault=fault, control=control)
 
 
 def _state_unchanged(eng):
@@ -128,6 +129,98 @@ def test_broken_timed_path_is_not_correct(cell, fault):
     assert not res["correct"], res["check"]
 
 
+def _token_altered_on(device: int):
+    """As ``_token_altered``, on one device's decode lane alone."""
+    def fault(eng):
+        from bench import serve
+
+        step = eng.decode_fn
+        broken_id = jax.devices()[device].id
+
+        def broken(params, k_pages, *rest):
+            here = serve.device_of(k_pages) == broken_id  # before the step donates the pages
+            k, v, st, logits = step(params, k_pages, *rest)
+            if not here:
+                return k, v, st, logits
+            other = (jnp.argmax(logits, axis=1) + 1) % logits.shape[1]
+            return k, v, st, logits.at[jnp.arange(logits.shape[0]), other].add(100.0)
+
+        eng.decode_fn = broken
+    return fault
+
+
+def _smallest_sample(config, traffic_mix):
+    """As ``tiny``, with a sample no larger than one request per device."""
+    spec, wl, cfg, traffic = tiny(config, traffic_mix)
+    cfg["check"]["sample_min_tokens"] = 1
+    return spec, wl, cfg, traffic
+
+
+@pytest.mark.parametrize("cell", CELLS[:1])
+def test_run_over_two_devices_is_correct(cell):
+    res = _run(cell, chips=2)
+    assert res["correct"], res["check"]
+    assert res["failed"] == 0 and res["stats"]["compiles_in_window"] == 0
+    assert set(res["stats"]["steps_by_device"]["decode"]) == {d.id for d in jax.devices()[:2]}
+    assert len(res["stats"]["pages_peak"]) == 2
+
+
+@pytest.mark.parametrize("device", [0, 1])
+def test_token_altered_on_one_device_is_not_correct(device):
+    """The sample holds a request of each device, so a fault in one
+    replica or lane shows whichever device it is on."""
+    res = _run(CELLS[0], chips=2, size=_smallest_sample, fault=_token_altered_on(device))
+    assert not res["correct"], res["check"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_warm_up_warms_every_device(cell):
+    """After ``warm_up`` over two devices, serving every palette length on
+    each of them, several rows a step, compiles nothing."""
+    import numpy as np
+
+    from bench import serve
+
+    _, wl, cfg, traffic = tiny(*cell)
+    devs = jax.devices()[:2]
+    eng = serve.build(cfg, devs, serve.make_weights(cfg, jax.random.PRNGKey(5), devs[0]), name=wl["name"])
+    probe = serve.Probe()
+    probe.wrap(eng)
+    palette = traffic["prompt_tokens"]["palette"]
+    serve.warm_up(eng, probe, palette)
+    assert {(p[0], p[3]) for p in probe.prefills} == {(T, d.id) for T in palette for d in devs}
+    assert {d[3] for d in probe.decodes} == {d.id for d in devs}
+    clock = run.CompileClock()
+    futs = [eng.submit(np.arange(1, T + 1, dtype=np.int32), 6, request_id=k)
+            for k, T in enumerate(palette * 2)]
+    for f in futs:
+        f.get(timeout=300)
+    eng.close()
+    assert {d[3] for d in probe.decodes} == {d.id for d in devs}
+    assert clock.count == 0
+
+
+def test_warm_up_stops_when_placement_skips_a_device():
+    """A placement that never reaches a device stops the warm-up, rather
+    than leaving that device's compiles to the window."""
+    from repro.core.scheduler import Scheduler
+
+    from bench import serve
+
+    _, wl, cfg, traffic = tiny(*CELLS[0])
+    devs = jax.devices()[:2]
+    eng = serve.build(cfg, devs, serve.make_weights(cfg, jax.random.PRNGKey(5), devs[0]), name=wl["name"])
+    probe = serve.Probe()
+    probe.wrap(eng)
+    first = next(pool.device for pool in eng.kv.pools.values() if pool.device.jax_device == devs[0])
+    eng._scheduler = Scheduler([first], policy="round_robin")
+    try:
+        with pytest.raises(RuntimeError, match="placement"):
+            serve.warm_up(eng, probe, traffic["prompt_tokens"]["palette"])
+    finally:
+        eng.close()
+
+
 def _weights_in_bf16(eng):
     """The served weights are stored in bfloat16 and widened to float32
     inside each step."""
@@ -162,8 +255,8 @@ def test_probe_counts_every_output_token(cell):
     from bench import serve
 
     _, wl, cfg, _ = tiny(*cell)
-    dev = jax.devices()[0]
-    eng = serve.build(cfg, dev, serve.make_weights(cfg, jax.random.PRNGKey(3), dev), name=wl["name"])
+    devs = jax.devices()[:1]
+    eng = serve.build(cfg, devs, serve.make_weights(cfg, jax.random.PRNGKey(3), devs[0]), name=wl["name"])
     probe = serve.Probe()
     probe.wrap(eng)
     lengths = [(16, 40), (32, 30), (16, 35)]  # three rows decoding at once pad to 4

@@ -57,10 +57,10 @@ def test_breakdown(synthetic):
 
 def _run_data(trace):
     cfg = json.loads((Path(run.BENCH) / "configs" / "olmo-1b.json").read_text())
-    decodes = [(8, np.array([1, 2]), np.array([100, 200])),
-               (8, np.array([1, 2]), np.array([101, 201])),
-               (4, np.array([3]), np.array([50]))]
-    samples = [(0.0, 3, 100), (0.1, 4, 300), (0.2, 2, 150)]
+    decodes = [(8, np.array([1, 2]), np.array([100, 200]), 0),
+               (8, np.array([1, 2]), np.array([101, 201]), 0),
+               (4, np.array([3]), np.array([50]), 0)]
+    samples = [(0.0, 3, (100,)), (0.1, 4, (300,)), (0.2, 2, (150,))]
     peak = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     return run.RunData(cfg, {"chips": 1}, trace, decodes, [1024], samples, 1535,
                        peak, dense, 4), cfg, peak
@@ -94,6 +94,42 @@ def test_readers_find_nothing_without_a_trace(synthetic):
         assert run._reader(name)(data) is None
     data.samples = []
     assert run._reader("kv_pages_used_share")(data) is None
+
+
+@pytest.fixture(scope="module")
+def two_devices():
+    raw = ProfileData.text_proto_to_serialized_xspace((DATA / "two_devices.pbtxt").read_text())
+    return xplane.reduce(ProfileData.from_serialized_xspace(raw))
+
+
+def test_each_device_gets_its_own_calls(two_devices):
+    """Two lanes' calls interleave in time on two threads; an execution goes
+    to a span of its own device, and the prefill and decode step that one
+    device runs in turn go to their own spans."""
+    t = two_devices
+    assert t.devices == [0, 1]
+    assert xplane.calls(t, MODULES, "decode") == [
+        (0, 10 * MS, 14 * MS), (1, 12 * MS, 16 * MS), (3, 19 * MS, 23 * MS),
+        (2, 20 * MS, 24 * MS), (4, 35 * MS, 39 * MS)]
+    assert xplane.calls(t, MODULES, "prefill") == [(0, 27 * MS, 35 * MS)]
+    assert xplane.span_call("bench.decode#3:2@1") == (3, 1)
+    assert xplane.span_call("bench.decode#3:2") == (3, None)
+
+
+def test_readers_over_two_devices(two_devices):
+    data, _, _ = _run_data(two_devices)
+    data.decodes = [(2, np.array([1]), np.array([100]), 0), (2, np.array([1]), np.array([50]), 1),
+                    (2, np.array([1]), np.array([101]), 0), (2, np.array([1]), np.array([51]), 1),
+                    (2, np.array([1]), np.array([102]), 0)]
+    data.samples = [(0.0, 2, (10, 40)), (0.1, 3, (60, 20)), (0.2, 3, (30, 55))]
+    data.pool_pages = 100
+    # Each lane's consecutive calls pair up (0 -> 2 -> 4 on device 0, 1 -> 3
+    # on device 1); the device-0 gap 24-35 ms holds the prefill's 8 ms.
+    assert run._reader("decode_host_gap_ms")(data) == pytest.approx((6 + 3 + 3) / 3)
+    assert run._reader("kv_pages_used_share")(data) == pytest.approx(60.0)
+    assert run._reader("decode_step_ms")(data) == pytest.approx(4.0)
+    # The devices are busy 20 and 8 ms of the 50 ms window.
+    assert run._reader("device_idle_share")(data) == pytest.approx(100 * (1 - 0.014 / 0.05))
 
 
 def test_recorded_tpu_trace():

@@ -3,6 +3,9 @@
 * device executions: the ``XLA Modules`` line of each TPU device plane, one
   event per run of a compiled program, named ``jit_<function>(<id>)`` with
   one id per compiled shape;
+* when each execution was handed to its device, on the host's clock: the
+  host's ``DoEnqueueProgram`` event with the execution's device
+  (``device_ordinal``) and ``run_id``, which each device counts on its own;
 * device ops: the ``XLA Ops`` line, whose union is the busy time;
 * host spans: every event on the host plane's threads, the benchmark's own
   ``TraceAnnotation`` spans among them, with the thread each ran on;
@@ -10,12 +13,15 @@
 
 jit names a program after its function, and the engine's two zoo steps are
 ``functools.partial`` objects, which jit names alike (``jit__unknown``).  So
-an execution is told apart by the span that launched it: the engine waits
-for every step's logits before its next step on that thread, so the
-execution launched by a ``bench.<kind>#<call>`` span starts after the span
-and ends before the host's next sync on that thread (``np.asarray``).
-Every compiled shape belongs to one kind, which the executions that only
-one kind's spans could have launched decide.
+an execution is told apart by the span that launched it,
+``bench.<kind>#<call>:<shape>@<device>``: the engine waits for every step's
+logits before its next step on that thread, so the execution launched by
+such a span is handed to that device after the span starts and before the
+host's next sync on that thread (``np.asarray``) ends.  Both ends are on
+the host's clock, so the device's clock, which the trace puts on the
+host's only to a few milliseconds, plays no part.  Every compiled shape
+belongs to one kind, which the executions that only one kind's spans could
+have launched decide.
 
 All times are nanoseconds on the trace's one clock.
 """
@@ -28,6 +34,7 @@ from dataclasses import dataclass, field
 
 WINDOW_SPAN = "bench.window"
 SYNC = "np.asarray"
+ENQUEUE = "DoEnqueueProgram"
 SKEW_NS = 5e6
 _DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 
@@ -38,6 +45,8 @@ class Trace:
     modules: "dict[int, list[tuple[str, float, float]]]" = field(default_factory=dict)
     ops: "dict[int, list[tuple[str, float, float]]]" = field(default_factory=dict)
     spans: "list[tuple[str, float, float, int]]" = field(default_factory=list)  # + host line
+    # device -> {start of an execution on it: when the host handed it over}
+    enqueued: "dict[int, dict[float, float]]" = field(default_factory=dict)
 
     @property
     def window_s(self) -> float:
@@ -66,19 +75,29 @@ def reduce(data) -> Trace:
     modules: dict = {}
     ops: dict = {}
     spans = []
+    run_ids: dict = {}      # device -> [(execution start, run_id)]
+    handed: dict = {}       # (device, run_id) -> enqueue time
     for plane in data.planes:
         m = _DEVICE_PLANE.match(plane.name)
         if m:
             dev = int(m.group(1))
             for line in plane.lines:
                 if line.name == "XLA Modules":
-                    modules[dev] = [(e.name, e.start_ns, e.end_ns) for e in line.events]
+                    evs = list(line.events)
+                    modules[dev] = [(e.name, e.start_ns, e.end_ns) for e in evs]
+                    run_ids[dev] = [(e.start_ns, dict(e.stats).get("run_id")) for e in evs]
                 elif line.name == "XLA Ops":
                     ops[dev] = [(e.name, e.start_ns, e.end_ns) for e in line.events]
         elif plane.name == "/host:CPU":
             for i, line in enumerate(plane.lines):
-                spans.extend((e.name, e.start_ns, e.end_ns, i) for e in line.events
-                             if e.end_ns > e.start_ns)
+                for e in line.events:
+                    if e.name == ENQUEUE:
+                        st = dict(e.stats)
+                        handed[(st.get("device_ordinal"), st.get("run_id"))] = e.start_ns
+                    if e.end_ns > e.start_ns:
+                        spans.append((e.name, e.start_ns, e.end_ns, i))
+    enqueued = {dev: {a: handed[(dev, r)] for a, r in runs if (dev, r) in handed}
+                for dev, runs in run_ids.items()}
     win = [s for s in spans if s[0] == WINDOW_SPAN]
     if win:
         window = (win[0][1], win[0][2])
@@ -86,7 +105,7 @@ def reduce(data) -> Trace:
         every = [e for evs in list(modules.values()) + list(ops.values()) for e in evs] + spans
         window = (min(e[1] for e in every), max(e[2] for e in every)) if every else (0.0, 0.0)
     spans = [s for s in spans if s[0] != WINDOW_SPAN]
-    return Trace(window, modules, ops, spans)
+    return Trace(window, modules, ops, spans, enqueued)
 
 
 def _clip(intervals, lo, hi):
@@ -129,8 +148,16 @@ def idle_gaps(trace: Trace, dev: int) -> "list[tuple[float, float]]":
 
 
 def span_kind(name: str) -> str:
-    """``bench.decode#12:8`` -> ``bench.decode``: a span's name without its call tag."""
+    """``bench.decode#12:8@0`` -> ``bench.decode``: a span's name without its call tag."""
     return name.split("#", 1)[0]
+
+
+def span_call(name: str) -> "tuple[int, int | None]":
+    """``bench.decode#12:8@0`` -> ``(12, 0)``: the call's index and the
+    device it ran on, ``None`` where the span does not say."""
+    tag = name.split("#", 1)[1]
+    _, at, dev = tag.partition("@")
+    return int(tag.split(":", 1)[0]), (int(dev) if at else None)
 
 
 def host_cause(trace: Trace, a: float, b: float) -> str:
@@ -157,9 +184,9 @@ def executions(trace: Trace, prefix: str) -> "list[tuple[str, float, float]]":
     return sorted(out, key=lambda e: e[1])
 
 
-def _launch_windows(trace: Trace, kind: str) -> "list[tuple[int, float, float]]":
-    """(call index, span start, end of the host's next sync on the span's
-    thread, else the start of the thread's next span of the kind)."""
+def _launch_windows(trace: Trace, kind: str) -> "list[tuple[int, int | None, float, float]]":
+    """(call index, device, span start, end of the host's next sync on the
+    span's thread, else the start of the thread's next span of the kind)."""
     spans = sorted((s, e, line, name) for name, s, e, line in trace.spans if span_kind(name) == kind)
     syncs: dict = {}
     for name, s, e, line in trace.spans:
@@ -172,57 +199,61 @@ def _launch_windows(trace: Trace, kind: str) -> "list[tuple[int, float, float]]"
         later = [t for t, _, ln, _ in spans[j + 1:] if ln == line]
         limit = later[0] if later else float("inf")
         sync = next((se for ss, se in syncs.get(line, []) if ss >= e), None)
-        out.append((int(name.split("#", 1)[1].split(":", 1)[0]), s,
-                    min(limit, sync) if sync is not None else limit))
+        out.append((*span_call(name), s, min(limit, sync) if sync is not None else limit))
     return out
 
 
 def calls(trace: Trace, modules: dict, kind: str) -> "list[tuple[int, float, float]]":
     """(call index, start, end) of each execution launched by a
-    ``bench.<kind>#<call>`` span inside the window.  ``modules`` maps each
-    kind to the module-name prefix of its program.
+    ``bench.<kind>#<call>`` span and handed to its device inside the window,
+    in time order.  ``modules`` maps each kind to the module-name prefix of
+    its program.
 
-    Each span launches one execution, inside its launch window (widened by
-    ``SKEW_NS``: the device's clock, as the trace puts it on the host's, is
-    off by a millisecond or two).  An execution that
-    only one free window holds is that window's; repeating this settles
-    the executions that two windows held.  A compiled shape belongs to the
-    kind its settled executions have most; any execution still open goes
-    to the latest span of its shape's kind that began before it."""
+    An execution's candidates are the launch windows, of spans on its own
+    device (or that name none), that hold the moment it was handed over.
+    One thread's windows do not overlap, so only a second thread launching
+    onto the same device makes a second candidate.  An execution that only
+    one free window holds is that window's.  When that settles nothing more,
+    each compiled shape takes the kind its settled executions have most, an
+    open execution keeps only the windows of its shape's kind, and settling
+    goes on.  An execution still open, or handed over at no recorded time,
+    is left out."""
     prefix = modules[kind]
     kinds = [k for k, p in modules.items() if p == prefix]
-    execs = executions(trace, prefix)
-    wins = [(k, i, s - SKEW_NS, e + SKEW_NS) for k in kinds
-            for i, s, e in _launch_windows(trace, "bench." + k)]
-    cands = [[(k, i) for k, i, s, e in wins if s <= a and b <= e] for _, a, b in execs]
+    wins = [(k, i, dev, s, e) for k in kinds for i, dev, s, e in _launch_windows(trace, "bench." + k)]
+    lo, hi = trace.window
+    execs = []
+    for d in trace.devices:
+        handed = trace.enqueued.get(d, {})
+        for n, a, b in trace.modules.get(d, []):
+            t = handed.get(a)
+            if n.startswith(prefix) and t is not None and lo <= t < hi:
+                execs.append((n, a, b, [(k, i) for k, i, dev, s, e in wins
+                                        if dev in (None, d) and s <= t <= e]))
     owner: dict = {}
     taken: set = set()
-    settled = True
-    while settled:
+    shape_kind: dict = {}
+    while True:
         settled = False
-        for j, cs in enumerate(cands):
-            free = [c for c in cs if c not in taken]
+        for j, (n, _, _, cands) in enumerate(execs):
+            free = [c for c in cands if c not in taken and shape_kind.get(n, c[0]) == c[0]]
             if j not in owner and len(free) == 1:
                 owner[j] = free[0]
                 taken.add(free[0])
                 settled = True
-    tally: dict = {}
-    for j, (k, _) in owner.items():
-        t = tally.setdefault(execs[j][0], {})
-        t[k] = t.get(k, 0) + 1
-    shape_kind = {n: max(t, key=t.get) for n, t in tally.items()}
-    alone = kinds[0] if len(kinds) == 1 else None
-    starts = sorted((s + SKEW_NS, i) for k, i, s, _ in wins if k == kind)
-    out = []
-    for j, (name, a, b) in enumerate(execs):
-        if j in owner:
-            if owner[j][0] == kind:
-                out.append((owner[j][1], a, b))
-        elif shape_kind.get(name, alone) == kind:
-            before = [i for s, i in starts if s <= a + SKEW_NS]
-            if before:
-                out.append((before[-1], a, b))
-    return out
+        if settled:
+            continue
+        tally: dict = {}
+        for j, (k, _) in owner.items():
+            t = tally.setdefault(execs[j][0], {})
+            t[k] = t.get(k, 0) + 1
+        learned = {n: max(t, key=t.get) for n, t in tally.items()}
+        if learned == shape_kind:
+            break
+        shape_kind = learned
+    out = [(owner[j][1], a, b) for j, (_, a, b, _) in enumerate(execs)
+           if j in owner and owner[j][0] == kind]
+    return sorted(out, key=lambda c: c[1])
 
 
 def breakdown(trace: Trace, top: int = 10) -> dict:
