@@ -1,17 +1,25 @@
-"""Seeded open-loop traffic: one generator for every mix in ``bench/traffic/``.
+"""Seeded traffic: one generator for every mix in ``bench/traffic/``.
 
-A mix file gives the mean arrival rate, the arrival process (Gamma
-inter-arrival times of a stated coefficient of variation; cv 1 is Poisson),
-and lognormal prompt and output lengths (median, sigma, clip range, and for
-prompts a palette that lengths are rounded up to).
+A mix file gives the arrival process and lognormal prompt and output
+lengths (median, sigma, clip range, and for prompts a palette that lengths
+are rounded up to).  The arrival process is open or closed:
 
-The lead-in and the measured window are two parts, each offered
-``round(rate x its seconds)`` requests that span it exactly.  Every seed
-gets the same sizes and gaps in each part, in the same order: the quantiles
-of the stated distributions, paired and ordered once by a fixed
-permutation.  The seed draws only the prompt token ids.  So two seeds offer
-the same work at the same times, and a run's spread measures the system
-rather than the draw.
+* open, ``{"process": "gamma", "cv": ...}`` with the mix's mean
+  ``rate_per_s``: Gamma inter-arrival times of the stated coefficient of
+  variation (cv 1 is Poisson).  The lead-in and the measured window are two
+  parts, each offered ``round(rate x its seconds)`` requests that span it
+  exactly;
+* closed, ``{"process": "closed", "clients": N, "requests": n}``: N clients
+  that each send the next request the moment their previous one resolves.
+  The schedule is one ordered list of ``n`` requests that carry no due time
+  (``due_s`` 0), taken in order by whichever client frees first; ``n`` is
+  many times what a run can serve, so the clients never run dry.
+
+Every seed gets the same sizes (and gaps) in each part, in the same order:
+the quantiles of the stated distributions, paired and ordered once by a
+fixed permutation.  The seed draws only the prompt token ids.  So two seeds
+offer the same work (at the same times), and a run's spread measures the
+system rather than the draw.
 """
 from __future__ import annotations
 
@@ -59,23 +67,41 @@ def gaps(arrival: dict, rate: float, n: int) -> np.ndarray:
     return g * (n / rate) / g.sum()
 
 
+def _sizes(traffic: dict, n: int, max_seq_len: int, base: np.random.Generator):
+    """``n`` (prompt length, output length) pairs, paired and ordered by
+    ``base``'s first two permutations."""
+    prompt = lengths(traffic["prompt_tokens"], n)[base.permutation(n)]
+    out = lengths(traffic["output_tokens"], n)[base.permutation(n)]
+    return prompt, np.minimum(out, max_seq_len - prompt)
+
+
 def _part(traffic: dict, n: int, span: float, offset: float, max_seq_len: int):
     """``n`` (due, prompt length, output length) spread over ``span`` seconds."""
     base = np.random.default_rng(0)  # fixed pairing and order, the same for every seed
-    prompt = lengths(traffic["prompt_tokens"], n)[base.permutation(n)]
-    out = lengths(traffic["output_tokens"], n)[base.permutation(n)]
+    prompt, out = _sizes(traffic, n, max_seq_len, base)
     gap = gaps(traffic["arrival"], n / span, n)[base.permutation(n)]
-    out = np.minimum(out, max_seq_len - prompt)
     due = offset + np.concatenate([[0.0], np.cumsum(gap)[:-1]])
     return zip(due.tolist(), prompt.tolist(), out.tolist())
 
 
+def is_closed(traffic: dict) -> bool:
+    return traffic["arrival"].get("process") == "closed"
+
+
 def schedule(traffic: dict, *, seed: int, seconds: float, vocab: int,
              max_seq_len: int, rate: "float | None" = None) -> "list[Request]":
-    """The lead-in's requests, then the window's, due from the origin on."""
+    """An open loop's requests: the lead-in's, then the window's, due from
+    the origin on.  A closed loop's: its ordered list, all due at 0."""
+    rng = rng_for(seed, 1)
+    if is_closed(traffic):
+        if rate is not None:
+            raise ValueError("a closed loop has no rate; vary its clients instead")
+        n = int(traffic["arrival"]["requests"])
+        prompt, out = _sizes(traffic, n, max_seq_len, np.random.default_rng(0))
+        return [Request(i, 0.0, rng.integers(1, vocab, size=int(T), dtype=np.int32), int(new))
+                for i, (T, new) in enumerate(zip(prompt.tolist(), out.tolist()))]
     rate = float(rate if rate is not None else traffic["rate_per_s"])
     lead = float(traffic["lead_in_s"])
-    rng = rng_for(seed, 1)
     reqs: list = []
     for span, offset in ((lead, 0.0), (float(seconds), lead)):
         n = max(1, round(rate * span))

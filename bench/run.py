@@ -17,9 +17,11 @@ The cell is an entry of ``BENCHMARK.json`` ``workloads``: a configuration
 4. warms every prefill and decode shape the traffic reaches on every chip,
    then runs a lead-in of the cell's own traffic (all of this is ``setup_s``);
 5. measures for ``--seconds``: an open loop submits each request at its due
-   time; with ``--trace 1`` the profiler records the first
-   ``TRACE_SECONDS`` of the window;
-6. waits for the requests due in the window, frees the engine, and checks a
+   time, a closed loop keeps its clients' requests in flight; with
+   ``--trace 1`` the profiler records the first ``TRACE_SECONDS`` of the
+   window;
+6. waits for the requests of the window (due in it; for a closed loop, sent
+   in it, its clients sending on meanwhile), frees the engine, and checks a
    seeded sample of them against the plain float32 reference, and the
    dtypes of the weights, pages and state the steps were handed against
    the configuration's;
@@ -122,10 +124,17 @@ class RunData:
     itemsize: int
 
 
+def counted_since(now: dict, before: dict) -> dict:
+    """The engine's plain counters' growth from ``before`` to ``now``."""
+    return {k: v - before.get(k, 0) for k, v in now.items() if not isinstance(v, dict)}
+
+
 def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, devices,
-             peak: dict, rate: "float | None" = None,
+             peak: dict, rate: "float | None" = None, clients: "int | None" = None,
              control: bool = False, fault=None, t_start: float = T_START) -> dict:
-    """One run of one cell on ``devices`` (JAX devices); returns the result's parts."""
+    """One run of one cell on ``devices`` (JAX devices); returns the result's parts.
+    A request belongs to the window when it is due in it; a closed loop's
+    request is due when it is sent, and its latency runs from then."""
     import jax
     import numpy as np
 
@@ -149,10 +158,16 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
     t_warm = time.perf_counter()
 
     origin = time.perf_counter() + 0.05
-    loop = serve.OpenLoop(eng, reqs, origin)
+    closed = loadgen.is_closed(traffic)
+    if closed:
+        clients = int(clients or traffic["arrival"]["clients"])
+        loop = serve.ClosedLoop(eng, reqs, clients)
+    else:
+        loop = serve.OpenLoop(eng, reqs, origin)
     ws = origin + float(traffic["lead_in_s"])
     we = ws + float(seconds)
     loop.run_until(ws, pools)
+    counted_before = eng.counters()
     compiles_before = clock.count
     setup_s = ws - t_start
     t_trace = None
@@ -170,6 +185,7 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
         jax.profiler.stop_trace()
         decodes, prefills = list(probe.decodes), [p[0] if p else None for p in probe.prefills]
     loop.run_until(we, pools)
+    counted = counted_since(eng.counters(), counted_before)
     compiles = clock.count - compiles_before
     in_window = [s for s in loop.sent if ws <= s.due < we]
     deadline = we + float(traffic["drain_limit_s"])
@@ -186,13 +202,17 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
             prefill_logits[key] = np.asarray(p[2])[0]
             home[key] = p[3]
     lat, norm, failed, finished, homes = [], [], 0, [], []
+    causes = Counter()  # why the window's failed requests failed
     for s in in_window:
         out = None
         if s.future is not None and s.future.done():
             try:
                 out = np.asarray(s.future.get())
-            except Exception:  # noqa: BLE001 - a failed request is counted
+            except Exception as exc:  # noqa: BLE001 - a failed request is counted
+                causes[f"{type(exc).__name__}: {exc}"[:300]] += 1
                 out = None
+        elif s.future is not None:
+            causes["unfinished at the drain limit"] += 1
         if out is None or s.done is None or out.size != s.req.max_new:
             failed += 1
             lat.append(math.inf)
@@ -244,10 +264,12 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
         "setup_s": setup_s, "memory_peak_bytes": memory_peak, "check": numbers,
         "control": ctl,
         "stats": {
-            "seed": seed, "rate_per_s": rate if rate is not None else traffic["rate_per_s"],
+            "seed": seed, "clients": clients if closed else None,
+            "rate_per_s": None if closed else rate if rate is not None else traffic["rate_per_s"],
             "compiles_in_window": compiles, "compile_s_total": clock.seconds,
             "warm_up_done_s": t_warm - t_start, "lead_in_s": float(traffic["lead_in_s"]),
             "due_in_window": len(in_window), "completed": len(finished), "failed": failed,
+            "failed_because": dict(causes),
             "latency_samples": len(lat), "tokens_in_window": tokens_in_window,
             "tokens_completed_in_window": tokens_completed,
             "lateness_p50_s": late[len(late) // 2] if late else None,
@@ -256,7 +278,8 @@ def run_cell(spec, wl, cfg, traffic, *, seed: int, seconds: float, trace: bool, 
             "backlog_end": backlog[-1] if backlog else None,
             "backlog_max": max(backlog) if backlog else None,
             "backlog_tenths": [backlog[i * (len(backlog) - 1) // 10] for i in range(11)] if backlog else None,
-            "offered_tokens_in_window": sum(s.req.max_new for s in in_window),
+            "offered_tokens_in_window": None if closed else sum(s.req.max_new for s in in_window),
+            "counted_in_window": counted,
             "drain_s": t_drained - we, "check_s": t_checked - t_drained,
             "pages_peak": pages_peak, "pool_pages": pool_pages, "steps_by_device": steps_by_device,
             "sample_requests": got["requests"], "sample_tokens": got["tokens"],
@@ -311,7 +334,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rate", type=float, default=None,
-                    help="override the mix's rate (the knee sweep); not for measured runs")
+                    help="override an open loop's rate (the knee sweep); not for measured runs")
+    ap.add_argument("--clients", type=int, default=None,
+                    help="override a closed loop's clients (the client sweep); not for measured runs")
     ap.add_argument("--control", type=int, choices=(0, 1), default=0,
                     help="also read the control over the sample (calibrate.py); not for measured runs")
     args = ap.parse_args(argv)
@@ -337,7 +362,7 @@ def main(argv=None) -> int:
         return 1
     res = run_cell(spec, wl, cfg, traffic, seed=args.seed, seconds=args.seconds,
                    trace=bool(args.trace), devices=jd[:wl["chips"]], peak=peaks[jd[0].device_kind],
-                   rate=args.rate, control=bool(args.control))
+                   rate=args.rate, clients=args.clients, control=bool(args.control))
     print("BENCH stats " + json.dumps(res["stats"]), flush=True)
     if args.control:
         print("BENCH control " + json.dumps({"program": {k: v["value"] for k, v in res["check"].items()},

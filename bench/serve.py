@@ -11,8 +11,9 @@ decode step with their resident lengths, when each step that makes output
 tokens is launched and how many it makes, and the floating dtypes of the
 weights, KV pages and resident state that the steps are handed and return.
 ``warm_up`` runs every prefill and decode shape the traffic can reach, on
-every device.  ``OpenLoop`` submits each request at its due time and
-records when its future resolves.
+every device.  ``OpenLoop`` submits each request at its due time,
+``ClosedLoop`` keeps a fixed number of requests in flight; both record when
+each future resolves.
 """
 from __future__ import annotations
 
@@ -249,3 +250,75 @@ class OpenLoop:
                 s.future.get(timeout=left)
             except Exception:  # noqa: BLE001 - failures are counted, not raised
                 pass
+
+
+class ClosedLoop:
+    """``clients`` clients, each sending the next request of ``requests``
+    (in order) the moment its previous one resolves, with no think time.
+    A request is due when it is sent: its ``Sent.due`` is its send time.
+    The interface is ``OpenLoop``'s; ``wait`` keeps the clients sending
+    until the requests it waits for have resolved, so that the last of them
+    see the same load as the rest."""
+
+    def __init__(self, eng, requests, clients: int):
+        self.eng = eng
+        self.requests = list(requests)
+        self.clients = int(clients)
+        self.sent: "list[Sent]" = []
+        self.next = 0
+        self.samples: "list[tuple[float, int, tuple[int, ...]]]" = []
+        self._open: "list[Sent]" = []
+        self._freed = threading.Event()
+
+    def _complete(self, s: Sent):
+        def cb(value):
+            with TraceAnnotation("bench.complete"):
+                s.done = time.perf_counter()
+            self._freed.set()
+            return value
+        return cb
+
+    def in_flight(self) -> int:
+        self._open = [s for s in self._open if s.done is None and not s.future.done()]
+        return len(self._open)
+
+    def _fill(self) -> None:
+        """Send requests until ``clients`` are in flight."""
+        self._freed.clear()
+        while self.in_flight() < self.clients:
+            if self.next == len(self.requests):
+                raise RuntimeError(f"closed loop: all {len(self.requests)} requests were sent; "
+                                   "the mix needs a longer list")
+            req = self.requests[self.next]
+            with TraceAnnotation("bench.submit"):
+                now = time.perf_counter()
+                s = Sent(req, now, now)
+                s.future = self.eng.submit(req.prompt, req.max_new, request_id=req.rid)
+                s.future.then(self._complete(s), executor="inline")
+            self.sent.append(s)
+            self._open.append(s)
+            self.next += 1
+
+    def run_until(self, t_end: float, pools=(), tick: float = 0.1) -> None:
+        """Keep ``clients`` requests in flight until ``t_end``; sample the
+        requests in flight and the page pools at ``tick`` intervals.  A
+        resolved request wakes the loop at once; one that fails is found by
+        the next tick's look."""
+        last = 0.0
+        while True:
+            now = time.perf_counter()
+            if now - last >= tick:
+                self.samples.append((now, self.in_flight(), tuple(p.used_pages for p in pools)))
+                last = now
+            if now >= t_end:
+                return
+            self._fill()
+            self._freed.wait(max(0.0, min(last + tick, t_end) - time.perf_counter()))
+
+    def wait(self, sent, deadline: float, tick: float = 0.1) -> None:
+        """Keep the clients sending until every request of ``sent`` has
+        resolved or ``deadline`` has passed; then send nothing more."""
+        while (any(s.done is None and not s.future.done() for s in sent)
+               and time.perf_counter() < deadline):
+            self._fill()
+            self._freed.wait(max(0.0, min(tick, deadline - time.perf_counter())))

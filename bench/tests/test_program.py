@@ -1,5 +1,5 @@
 """The readers of the program's own spans and counters (``bench/program.py``
-and the five metrics that use it) on a hand-made trace whose values are
+and the six metrics that use it) on hand-made traces whose values are
 known, and on traces of a program that records none."""
 import json
 from pathlib import Path
@@ -13,7 +13,7 @@ from bench.work import dense
 DATA = Path(__file__).resolve().parent / "data"
 CELL = "olmo-1b.conv"
 READERS = ("admission_wait_ms", "decode_sitout_share", "kv_host_ms_per_ktok",
-           "decode_state_host_ms", "host_copy_mb_per_token")
+           "decode_state_host_ms", "host_copy_mb_per_token", "kv_spill_pages_per_ktok")
 
 
 def _write_trace(root: Path, pbtxt: str) -> xplane.Trace:
@@ -62,6 +62,16 @@ def test_readers(trace_root):
     assert read["decode_state_host_ms"] == pytest.approx(4.0)
     moved = 4096 + 4000000 + 2 * 8388608 + 400000 + 4000000
     assert read["host_copy_mb_per_token"] == pytest.approx(moved / 1e6 / 5)
+    # Prompts prefilled in the window, and no page spilled or fetched back.
+    assert read["kv_spill_pages_per_ktok"] == 0
+
+
+def test_spill_reader_counts_the_window_only(trace_root):
+    data = _run_data(_write_trace(trace_root, "spill.pbtxt"))
+    c = program.counts(data)
+    assert (c["prefill_tokens"], c["spilled_pages"], c["refetched_pages"]) == (2048, 48, 24)
+    # (40 + 8) pages spilled and 24 fetched back over 2 x 1,024 prompt tokens.
+    assert run._reader("kv_spill_pages_per_ktok")(data) == pytest.approx(72 / 2.048)
 
 
 def test_readers_find_nothing_in_a_program_that_records_none(trace_root):

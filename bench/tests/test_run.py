@@ -6,23 +6,33 @@ bfloat16 where the configuration states float32.  The fp8 control, put in
 the program's place and judged by the configuration's limits, comes out
 not correct at the model's widths cut to a size the CPU holds.
 
+The closed loop on a synthetic engine: never more requests in flight than
+clients, the next sent only when one resolves, the window's requests
+counted by send time, and the clients sending on while they drain.
+
 Also: without a TPU, or without the system under test beside ``bench/``,
 ``run.py`` exits non-zero and prints no result."""
 import os
 import shutil
 import subprocess
 import sys
+import threading
+import time
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from bench import run
+from bench import loadgen, run, serve
 
 ROOT = Path(__file__).resolve().parents[2]
-# The benchmark's two cells.
-CELLS = [("olmo-1b", "conv"), ("mamba2-130m", "chat-burst")]
+# The benchmark's one-chip cells, and a closed loop (``conv-batch``, a cell
+# that BENCHMARK.json leaves out while the program fails requests when its
+# page pool is overcommitted, PERF.md section 7) at a size that does not
+# overcommit the pool.
+CELLS = [("olmo-1b", "conv"), ("mamba2-130m", "chat-burst"), ("olmo-1b", "conv-batch")]
 PEAK = {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 
 
@@ -40,7 +50,11 @@ def tiny(config, traffic_mix):
         m.update(num_layers=2, d_model=64, vocab_size=256)
         m["ssm"] = dict(m["ssm"], d_state=16, head_dim=16, chunk=16)
     cfg["engine"].update(max_seq_len=128, pool_bytes=8 << 20, decode_max_batch=4, decode_shapes=[1, 2, 4])
-    traffic.update(rate_per_s=6.0, lead_in_s=1.0, drain_limit_s=30)
+    if loadgen.is_closed(traffic):  # more clients than the decode cap, as on the chip
+        traffic["arrival"].update(clients=6, requests=1024)
+    else:
+        traffic.update(rate_per_s=6.0)
+    traffic.update(lead_in_s=1.0, drain_limit_s=30)
     traffic["prompt_tokens"].update(median=24, min=8, max=64, palette=[16, 32, 64])
     traffic["output_tokens"].update(median=8, min=2, max=16)
     return spec, wl, cfg, traffic
@@ -111,10 +125,15 @@ def test_sound_run_is_correct(cell):
     assert res["stats"]["sample_tokens"] > 0
     e2e = res["end_to_end"]
     assert e2e["output_tokens_per_s"] > 0 and e2e["latency_p90_s"] > 0 and e2e["setup_s"] > 0
+    closed = cell[1] == "conv-batch"
+    assert (res["stats"]["clients"] == 6) == closed
+    assert (res["stats"]["offered_tokens_in_window"] is None) == closed
+    assert (res["stats"]["rate_per_s"] is None) == closed
     # The result line carries the cell's own end-to-end metrics, and the
     # compared numbers last.
     spec = run.load_json(ROOT / "BENCHMARK.json")
-    wl = next(w for w in spec["workloads"] if (w["config"], w["traffic"]) == cell)
+    wl = next((w for w in spec["workloads"] if (w["config"], w["traffic"]) == cell),
+              {"name": ".".join(cell), "chips": 1})
     line = run.result_line(spec, wl, res, jax.devices(), False)
     assert set(line["metrics"]) == {m["name"] for m in run.metrics_for(spec, wl["name"], "end_to_end")}
     assert "setup_s" in line["metrics"] and len(line["metrics"]) >= 2
@@ -127,6 +146,27 @@ def test_sound_run_is_correct(cell):
 def test_broken_timed_path_is_not_correct(cell, fault):
     res = _run(cell, fault=fault)
     assert not res["correct"], res["check"]
+
+
+def _prompts_fail(eng):
+    """Every traffic prompt's prefill raises (warm-up prompts are all ones)."""
+    step = eng.prefill_fn
+
+    def broken(params, tokens, extras):
+        if int(np.asarray(tokens).max()) > 1:
+            raise RuntimeError("planted prefill failure")
+        return step(params, tokens, extras)
+
+    eng.prefill_fn = broken
+
+
+@pytest.mark.parametrize("cell", [CELLS[0], CELLS[2]])
+def test_failed_requests_are_counted_with_their_cause(cell):
+    res = _run(cell, fault=_prompts_fail)
+    assert not res["correct"]
+    assert res["failed"] == res["attempted"] > 0
+    assert res["stats"]["failed_because"] == {"RuntimeError: planted prefill failure": res["failed"]}
+    assert res["end_to_end"]["latency_p90_s"] == float("inf")
 
 
 def _token_altered_on(device: int):
@@ -293,3 +333,111 @@ def test_benchmark_alone_no_result(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__", "data"))
     p = _bench(["--workload", "olmo-1b.conv", "--seed", "1", "--seconds", "1", "--trace", "0"], tmp_path)
     assert p.returncode != 0 and p.stdout.strip() == ""
+
+
+class _Held:
+    """A synthetic engine whose requests resolve only when the test says."""
+
+    def __init__(self):
+        self.promises = []
+        self.most_open = 0
+
+    def submit(self, prompt, max_new, request_id):
+        from repro.core.futures import Promise
+
+        p = Promise()
+        self.promises.append((p, max_new))
+        self.most_open = max(self.most_open, sum(1 for q, _ in self.promises if not q.get_future().done()))
+        return p.get_future()
+
+
+def _requests(n):
+    return [loadgen.Request(i, 0.0, np.ones(4, np.int32), 3) for i in range(n)]
+
+
+def test_closed_loop_sends_only_when_a_request_resolves():
+    eng = _Held()
+    loop = serve.ClosedLoop(eng, _requests(50), clients=3)
+    loop.run_until(time.perf_counter() + 0.15)
+    assert loop.next == 3 and loop.in_flight() == 3
+    p, n = eng.promises[1]
+    p.set_value(np.zeros(n, np.int32))
+    loop.run_until(time.perf_counter() + 0.15)
+    assert loop.next == 4 and loop.in_flight() == 3 and loop.sent[1].done is not None
+    eng.promises[0][0].set_exception(RuntimeError("failed"))  # found at the next tick
+    loop.run_until(time.perf_counter() + 0.25)
+    assert loop.next == 5 and loop.in_flight() == 3
+    assert eng.most_open == 3
+    assert [s.req.rid for s in loop.sent] == [0, 1, 2, 3, 4]
+    assert all(s.due == s.sent for s in loop.sent)
+
+
+class _Timed:
+    """A synthetic engine that serves each request in ``serve_s``, and
+    records how many were open as each one arrived."""
+
+    def __init__(self, serve_s):
+        self.serve_s = serve_s
+        self.lock = threading.Lock()
+        self.open = 0
+        self.arrivals = []  # (time, open before it)
+        self.timers = []
+
+    def submit(self, prompt, max_new, request_id):
+        from repro.core.futures import Promise
+
+        p = Promise()
+        with self.lock:
+            self.arrivals.append((time.perf_counter(), self.open))
+            self.open += 1
+
+        def finish():
+            with self.lock:
+                self.open -= 1
+            p.set_value(np.zeros(max_new, np.int32))
+
+        t = threading.Timer(self.serve_s * (1 + request_id % 3), finish)
+        self.timers.append(t)
+        t.start()
+        return p.get_future()
+
+
+def test_closed_loop_window_by_send_time_and_load_through_the_drain():
+    clients = 4
+    eng = _Timed(0.04)
+    loop = serve.ClosedLoop(eng, _requests(400), clients)
+    loop.run_until(time.perf_counter() + 0.3)
+    ws = time.perf_counter()
+    we = ws + 0.6
+    loop.run_until(we)
+    in_window = [s for s in loop.sent if ws <= s.due < we]
+    assert len(in_window) == sum(1 for t, _ in eng.arrivals if ws <= t < we) > clients
+    # Sent before the window and resolved in it: not the window's.
+    assert any(s.sent < ws <= s.done < we for s in loop.sent if s.done is not None)
+    loop.wait(in_window, we + 10.0)
+    assert all(s.done is not None for s in in_window)
+    after = [s for s in loop.sent if s.sent >= we]
+    assert after  # the clients sent on while the window's requests drained
+    last_done = max(s.done for s in in_window)
+    assert all(s.sent <= last_done + 0.01 for s in after)
+    # Never more than the clients in flight: each send past the first
+    # ``clients`` follows a resolution.  Each request that resolved before
+    # the window's last had a successor sent (one that resolved in the same
+    # instant as that last one may not).  A future resolves a moment before
+    # its callback stamps ``done``, so the upper count reads the futures.
+    assert max(o for _, o in eng.arrivals) == clients - 1
+    replaced = len(loop.sent) - clients
+    resolved_before = sum(1 for s in loop.sent if s.done is not None and s.done < last_done)
+    assert resolved_before - 1 <= replaced <= sum(1 for s in loop.sent if s.future.done())
+    for t in eng.timers:
+        t.join(timeout=5)
+        assert not t.is_alive()
+
+
+def test_closed_loop_that_runs_dry_stops_the_run():
+    eng = _Timed(0.01)
+    loop = serve.ClosedLoop(eng, _requests(6), clients=2)
+    with pytest.raises(RuntimeError, match="all 6 requests were sent"):
+        loop.run_until(time.perf_counter() + 1.0)
+    for t in eng.timers:
+        t.join(timeout=5)
