@@ -503,6 +503,55 @@ def paged_decode_attend(q, kp, vp, tables, lengths):
     return attention(q, kc, vc, causal=False, valid_len=lengths + 1)
 
 
+def slab_decode_attend(q, k_new, v_new, k_pages, v_pages, layer, tables, positions, lengths):
+    """One-token GQA attention of layer ``layer`` read in place from the
+    full multi-layer slabs, which it leaves unchanged.
+
+    q: (B, 1, H, D); k_new/v_new: (B, 1, K, D), this step's token;
+    k_pages/v_pages: (L, N, P, K, D) slabs; tables: (B, M); positions ==
+    lengths: (B,).  Each row's pages are gathered straight out of the slab
+    into a (B, M*P, K, D) cache, and the row's own new token is set into
+    that copy at ``positions``: the values attended over are bit-for-bit
+    those ``page_scatter`` then ``paged_decode_attend`` would give.
+
+    Returns ``(o, k_row, v_row)``: the attention output and the (B, K, D)
+    K and V read back from the copies at ``positions``, which are what
+    ``slab_write_tokens`` writes into the slabs after the layer scan, so
+    the pages hold exactly the values attended over.
+    """
+    B, M = tables.shape
+    _, _, P, K, D = k_pages.shape
+    rows = jnp.arange(B)
+
+    def cache(pages, new):
+        c = pages[layer, tables].reshape(B, M * P, K, D)
+        return c.at[rows, positions].set(new[:, 0].astype(c.dtype))
+
+    kc, vc = cache(k_pages, k_new), cache(v_pages, v_new)
+    o = attention(q, kc, vc, causal=False, valid_len=lengths + 1)
+    return o, kc[rows, positions], vc[rows, positions]
+
+
+def slab_write_tokens(k_pages, v_pages, k_steps, v_steps, tables, positions):
+    """Write one decode step's token of every layer into the slabs in one
+    scatter per slab: the only write a step makes to its pages.
+
+    k_pages/v_pages: (L, N, P, K, D) slabs (donated by the caller, so the
+    scatter is in place); k_steps/v_steps: (L, B, K, D), the token of each
+    layer and row; tables: (B, M); positions: (B,).  Token ``t`` of row
+    ``b`` lands at ``[:, table[b, t // P], t % P]``, as ``page_scatter``
+    places it.  Rows that repeat another row's table, position and token
+    (the decode lane's pad rows) write identical values at identical
+    indices, so the order of the scatter cannot matter.
+    """
+    P = k_pages.shape[2]
+    page = jnp.take_along_axis(tables, positions[:, None] // P, axis=1)[:, 0]
+    slot = positions % P
+    k_pages = k_pages.at[:, page, slot].set(k_steps.astype(k_pages.dtype))
+    v_pages = v_pages.at[:, page, slot].set(v_steps.astype(v_pages.dtype))
+    return k_pages, v_pages
+
+
 def ring_gather(pages, tables, positions, ring: int):
     """Reconstruct a sliding-window ring cache (B, ring, K, D) from paged
     full-history KV.

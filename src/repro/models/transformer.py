@@ -284,9 +284,14 @@ def paged_decode_step(cfg, params, k_pages, v_pages, state, tokens, positions, t
     tokens; positions == lengths: (B,) per-row write slot / tokens already
     resident; tables: (B, M).  Returns (k_pages, v_pages, state, logits
     (B, V)).  Per-row math is op-for-op ``decode_step``'s — the new token
-    is scattered at ``positions`` and each row attends over
-    ``lengths + 1`` slots — so greedy tokens are bit-identical to the
-    padded oracle.
+    sits at ``positions`` and each row attends over ``lengths + 1`` slots
+    — so greedy tokens are bit-identical to the padded oracle.
+
+    Write contract: the layer scan reads the slabs in place, as loop
+    invariants (``L.slab_decode_attend``), and carries only each layer's
+    new (B, K, hd) K and V out; after the scan one scatter per slab writes
+    the step's tokens (``L.slab_write_tokens``).  The slabs are written
+    nowhere else, so with the slabs donated no slab-sized array is made.
     """
     tokens = tokens.reshape(-1, 1)
     x = L.embed(cfg, params["embed"], tokens)
@@ -300,14 +305,13 @@ def paged_decode_step(cfg, params, k_pages, v_pages, state, tokens, positions, t
         cos, sin = None, None
 
     def body(x, xs):
-        lp, kp, vp = xs
+        lp, l = xs
         h = L.apply_norm(cfg, x, lp["ln1"])
         q, k, v = L.qkv_proj(cfg, lp["attn"], h)
         if cos is not None:
             q = L.apply_rope(q, cos, sin)
             k = L.apply_rope(k, cos, sin)
-        kp, vp = L.page_scatter(kp, vp, k, v, tables, positions)
-        o = L.paged_decode_attend(q, kp, vp, tables, lengths)
+        o, k_row, v_row = L.slab_decode_attend(q, k, v, k_pages, v_pages, l, tables, positions, lengths)
         x = x + L.out_proj(cfg, lp["attn"], o)
         h = L.apply_norm(cfg, x, lp["ln2"])
         if cfg.moe is not None:
@@ -316,9 +320,11 @@ def paged_decode_step(cfg, params, k_pages, v_pages, state, tokens, positions, t
             y, _ = M.moe_block(cfg, lp["moe"], h, groups=B)
         else:
             y = L.mlp(cfg, lp["mlp"], h)
-        return x + y, (kp, vp)
+        return x + y, (k_row, v_row)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], k_pages, v_pages))
+    layer_ids = jnp.arange(cfg.num_layers)
+    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], layer_ids))
+    k_pages, v_pages = L.slab_write_tokens(k_pages, v_pages, ks, vs, tables, positions)
     x = L.apply_norm(cfg, x, params["final_norm"])
     logits = L.unembed(cfg, params["embed"], x)
-    return ks, vs, state, logits[:, 0]
+    return k_pages, v_pages, state, logits[:, 0]

@@ -172,6 +172,50 @@ def test_zoo_greedy_parity_bitwise(arch, device):
         assert g == w, f"{arch} T={len(p)}: paged {g} != oracle {w}"
 
 
+def test_decode_step_writes_each_rows_token_once_in_place():
+    """One transformer decode step over a batch padded the way the decode
+    lane pads it (pad rows repeat the last row) changes the slabs only at
+    ``(l, table[b, pos // P], pos % P)`` for each real row and layer, and
+    writes there exactly the K/V the padded oracle makes for that row."""
+    from repro.models import transformer
+    from repro.serving.paged import zoo_steps
+
+    cfg, params = _setup("olmo-1b")
+    rng = np.random.default_rng(11)
+    L_, K, D, N = cfg.num_layers, cfg.num_kv_heads, cfg.hd, 8
+    shape = (L_, N, PAGE, K, D)
+    k0 = rng.normal(size=shape).astype(np.float32)
+    v0 = rng.normal(size=shape).astype(np.float32)
+    # mid-page / first slot of a new page / last slot of a page; page 0
+    # pads the tables, as the pool's sentinel does
+    tables = np.asarray([[1, 0, 0], [2, 3, 0], [4, 5, 0]], np.int32)
+    positions = np.asarray([5, 16, 31], np.int32)
+    tokens = rng.integers(1, cfg.vocab_size, size=3).astype(np.int32)
+    pad = lambda a: np.concatenate([a, a[-1:]])  # noqa: E731 - 3 real rows -> 4
+
+    _, _, dec = zoo_steps(cfg)
+    k1, v1, _, _ = dec(params, jnp.asarray(k0), jnp.asarray(v0), None, pad(tokens),
+                       pad(positions), pad(tables), pad(positions))
+    k1, v1 = np.asarray(k1), np.asarray(v1)
+
+    oracle = jax.jit(functools.partial(transformer.decode_step, cfg, params))
+    written = np.zeros(shape[:3], bool)
+    for b in range(3):
+        page, slot = tables[b, positions[b] // PAGE], positions[b] % PAGE
+        written[:, page, slot] = True
+        # the oracle at the same batch size: the row's pages, read as one
+        # dense cache, in each of its 4 rows
+        dense = {n: jnp.asarray(np.broadcast_to(
+            s[:, tables[b]].reshape(L_, 1, MAX_SEQ, K, D), (L_, 4, MAX_SEQ, K, D)))
+            for n, s in (("k", k0), ("v", v0))}
+        _, cache = oracle(dense, jnp.full((4, 1), tokens[b], jnp.int32),
+                          jnp.int32(positions[b]))
+        np.testing.assert_array_equal(k1[:, page, slot], np.asarray(cache["k"])[:, 0, positions[b]])
+        np.testing.assert_array_equal(v1[:, page, slot], np.asarray(cache["v"])[:, 0, positions[b]])
+    np.testing.assert_array_equal(k1[~written], k0[~written])
+    np.testing.assert_array_equal(v1[~written], v0[~written])
+
+
 def test_zoo_two_model_fleet_interleaved(device):
     """Two engines over different families serve concurrently on one
     device pool without cross-talk (the tutorial §10 shape)."""

@@ -11,6 +11,7 @@ described inside a module fixture (never at import), and the whole check
 stays in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +110,58 @@ def test_olmo_1b_decode_step_takes_weights_as_arguments(one_chip):
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + (
         mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 16e9  # one v5e chip
+
+
+def _computation_roots(hlo: str) -> dict:
+    """Name of each computation in an HLO text -> opcode of its ROOT."""
+    roots, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+        root = re.match(r"\s+ROOT %\S+ = \S+ ([\w-]+)\(", line)
+        if root and name:
+            roots[name] = root.group(1)
+    return roots
+
+
+def test_olmo_1b_decode_step_writes_slabs_in_place(one_chip):
+    """At the olmo-1b cell's size (3.5 GiB pool, 8 rows, a 128-page table)
+    the decode step makes no slab-shaped array: the slabs reach the layer
+    scan as parameters, and the only instruction of their shape that is
+    not a parameter or an alias of one is the scatter of the step's
+    tokens into the donated slab."""
+    from repro.configs import get_config
+    from repro.models.model import get_model
+    from repro.serving.paged import zoo_steps
+
+    cfg = get_config("olmo-1b")
+    shapes = jax.eval_shape(lambda k: get_model(cfg).init(cfg, k), jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _spec(s.shape, one_chip, s.dtype), shapes)
+    spec_fn, _, dec = zoo_steps(cfg)
+    spec = spec_fn(cfg)
+    shape = (spec.layers, int(3.5 * (1 << 30)) // spec.page_bytes, spec.page_size,
+             spec.kv_heads, spec.head_dim)
+    slab = _spec(shape, one_chip)
+    B, M = 8, 128
+    i32 = lambda *s: _spec(s, one_chip, jnp.int32)  # noqa: E731
+    c = dec.lower(params, slab, slab, None, i32(B), i32(B), i32(B, M), i32(B)).compile()
+    hlo = c.as_text()
+    roots = _computation_roots(hlo)
+    slab_shaped = re.compile(
+        r"\s+(?:ROOT )?%(\S+) = f32\[" + ",".join(map(str, shape))
+        + r"\]\{[^}]*\} ([\w-]+)\((.*)")
+    made = []
+    for line in hlo.splitlines():
+        m = slab_shaped.match(line)
+        if not m or m.group(2) in ("parameter", "get-tuple-element", "bitcast", "scatter"):
+            continue
+        called = re.search(r"calls=%([\w.-]+)", m.group(3))
+        if m.group(2) == "fusion" and called and roots.get(called.group(1)) == "scatter":
+            continue
+        made.append(f"{m.group(1)} = {m.group(2)}")
+    assert not made, made
+    assert sum(1 for r in roots.values() if r == "scatter") >= 2
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * slab.size * 4  # both slabs in place
+    assert mem.temp_size_in_bytes < 3e9
