@@ -34,45 +34,44 @@ def make_model():
     Dm = HEADS * HEAD_DIM
     rng = np.random.default_rng(0)
     s = 1.0 / np.sqrt(Dm)
-    emb = jnp.asarray(rng.normal(size=(VOCAB, Dm)).astype(np.float32) * s)
-    wq = jnp.asarray(rng.normal(size=(Dm, Dm)).astype(np.float32) * s)
-    wk = jnp.asarray(rng.normal(size=(Dm, KV_HEADS * HEAD_DIM)).astype(np.float32) * s)
-    wv = jnp.asarray(rng.normal(size=(Dm, KV_HEADS * HEAD_DIM)).astype(np.float32) * s)
-    wu = jnp.asarray(rng.normal(size=(Dm, VOCAB)).astype(np.float32) * s)
+    shapes = {"emb": (VOCAB, Dm), "wq": (Dm, Dm), "wk": (Dm, KV_HEADS * HEAD_DIM),
+              "wv": (Dm, KV_HEADS * HEAD_DIM), "wu": (Dm, VOCAB)}
+    params = {n: rng.normal(size=sh).astype(np.float32) * s for n, sh in shapes.items()}
 
     @jax.jit
-    def prefill_fn(tokens):
-        x = emb[tokens]                                   # (B, T, Dm)
+    def prefill_fn(p, tokens, extras):
+        x = p["emb"][tokens]                              # (B, T, Dm)
         B, T, _ = x.shape
-        k = (x @ wk).reshape(B, T, KV_HEADS, HEAD_DIM)
-        v = (x @ wv).reshape(B, T, KV_HEADS, HEAD_DIM)
-        q = (x[:, -1] @ wq).reshape(B, KV_HEADS, HEADS // KV_HEADS, HEAD_DIM)
+        k = (x @ p["wk"]).reshape(B, T, KV_HEADS, HEAD_DIM)
+        v = (x @ p["wv"]).reshape(B, T, KV_HEADS, HEAD_DIM)
+        q = (x[:, -1] @ p["wq"]).reshape(B, KV_HEADS, HEADS // KV_HEADS, HEAD_DIM)
         sc = jnp.einsum("bkrd,btkd->bkrt", q, k) / np.sqrt(HEAD_DIM)
         o = jnp.einsum("bkrt,btkd->bkrd", jax.nn.softmax(sc, -1), v)
-        nxt = jnp.argmax(o.reshape(B, Dm) @ wu, -1).astype(jnp.int32)
-        return k[:, None], v[:, None], nxt                # KV gains a layer axis
+        # KV gains a layer axis; no resident state; the engine samples
+        return k[:, None], v[:, None], None, o.reshape(B, Dm) @ p["wu"]
 
     @jax.jit
-    def decode_fn(kp, vp, tokens, positions, tables, lengths):
-        x = emb[tokens]                                   # (b, Dm)
+    def decode_fn(p, kp, vp, state, tokens, positions, tables, lengths):
+        x = p["emb"][tokens]                              # (b, Dm)
         b = tokens.shape[0]
-        q = (x @ wq).reshape(b, HEADS, HEAD_DIM)
-        k = (x @ wk).reshape(b, KV_HEADS, HEAD_DIM)
-        v = (x @ wv).reshape(b, KV_HEADS, HEAD_DIM)
+        q = (x @ p["wq"]).reshape(b, HEADS, HEAD_DIM)
+        k = (x @ p["wk"]).reshape(b, KV_HEADS, HEAD_DIM)
+        v = (x @ p["wv"]).reshape(b, KV_HEADS, HEAD_DIM)
         page = tables[jnp.arange(b), positions // PAGE]
         kp = kp.at[0, page, positions % PAGE].set(k)      # scatter the new token
         vp = vp.at[0, page, positions % PAGE].set(v)
         o = paged_attention_ref(q, kp[0], vp[0], tables, lengths + 1)
-        nxt = jnp.argmax(o.reshape(b, Dm) @ wu, -1).astype(jnp.int32)
-        return kp, vp, nxt
+        return kp, vp, None, o.reshape(b, Dm) @ p["wu"]
 
-    return prefill_fn, decode_fn
+    return params, prefill_fn, decode_fn
 
 
 def main() -> None:
+    import jax
+
     from repro.serving import LanePolicy, PagedKVCache, PagedServeEngine, PageSpec
 
-    prefill_fn, decode_fn = make_model()
+    params, prefill_fn, decode_fn = make_model()
     # prefill groups same-length prompts (no intra-group padding), so the
     # stream repeats a few lengths the way real traffic repeats templates
     prompts = [np.arange(n, dtype=np.int32) % VOCAB
@@ -81,8 +80,11 @@ def main() -> None:
 
     def serve(label, **policies):
         kv = PagedKVCache(PageSpec(1, PAGE, KV_HEADS, HEAD_DIM), pool_pages=64)
-        with PagedServeEngine(kv, prefill_fn, decode_fn, max_seq_len=MAXLEN,
-                              name=label, **policies) as eng:
+        # one params replica per pool device, keyed by device key
+        weights = {key: jax.device_put(params, pool.device.jax_device)
+                   for key, pool in kv.pools.items()}
+        with PagedServeEngine(kv, prefill_fn, decode_fn, weights=weights,
+                              max_seq_len=MAXLEN, name=label, **policies) as eng:
             futs = [eng.submit(p, max_new_tokens=new) for p in prompts]
             outs = [np.asarray(f.get()) for f in futs]
             m = eng.metrics()

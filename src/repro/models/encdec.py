@@ -218,11 +218,11 @@ def decode_step(cfg, params, cache, tokens, pos, *, positions=None):
 def paged_spec(cfg):
     """Decoder self-KV lives in pages; the fixed-size cross K/V (one
     entry per encoder frame, never grows) rides as per-sequence state."""
-    from repro.serving.paged import PageSpec
+    from repro.serving.paged import PAGE_SIZE, PageSpec
 
     return PageSpec(
         layers=cfg.num_layers,
-        page_size=0,
+        page_size=PAGE_SIZE,
         kv_heads=cfg.num_kv_heads,
         head_dim=cfg.hd,
         dtype=jnp.float32,

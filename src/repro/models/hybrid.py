@@ -293,11 +293,11 @@ def paged_spec(cfg):
     ``cache_update(..., ring=...)`` would hold is reconstructed at decode
     via ``layers.ring_gather`` — so pages stay position-addressed for
     every layer and one table serves the whole stack."""
-    from repro.serving.paged import PageSpec
+    from repro.serving.paged import PAGE_SIZE, PageSpec
 
     return PageSpec(
         layers=len(kv_producers(cfg)),
-        page_size=0,
+        page_size=PAGE_SIZE,
         kv_heads=cfg.num_kv_heads,
         head_dim=cfg.hd,
         dtype=jnp.float32,

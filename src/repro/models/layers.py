@@ -496,7 +496,7 @@ def paged_decode_attend(q, kp, vp, tables, lengths):
 
     q: (B, 1, H, D); kp/vp: (N, P, K, D); lengths: (B,) tokens already
     resident EXCLUDING the one scattered this step (so rows attend over
-    ``lengths + 1`` slots — the fig9 toy's contract).
+    ``lengths + 1`` slots — the ``PagedServeEngine`` decode contract).
     """
     kc = page_gather(kp, tables)
     vc = page_gather(vp, tables)

@@ -98,9 +98,9 @@ def paged_spec(cfg):
     page machinery (tables, placement, defrag) uniform while the real
     memory — the recurrent state — rides as per-sequence resident state
     whose bytes the sequence's AGAS registration carries."""
-    from repro.serving.paged import PageSpec
+    from repro.serving.paged import PAGE_SIZE, PageSpec
 
-    return PageSpec(layers=1, page_size=0, kv_heads=1, head_dim=1, dtype=jnp.float32)
+    return PageSpec(layers=1, page_size=PAGE_SIZE, kv_heads=1, head_dim=1, dtype=jnp.float32)
 
 
 def paged_prefill(cfg, params, tokens, extras=None):

@@ -246,11 +246,11 @@ def decode_step(cfg, params, cache, tokens, pos, *, positions=None):
 def paged_spec(cfg):
     """Multi-layer KV folded into ONE page geometry: layer is the leading
     slab dim, so a sequence's pages for every layer share one table."""
-    from repro.serving.paged import PageSpec
+    from repro.serving.paged import PAGE_SIZE, PageSpec
 
     return PageSpec(
         layers=cfg.num_layers,
-        page_size=0,  # 0 -> REPRO_PAGE_SIZE default
+        page_size=PAGE_SIZE,
         kv_heads=cfg.num_kv_heads,
         head_dim=cfg.hd,
         dtype=jnp.float32,

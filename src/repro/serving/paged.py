@@ -50,22 +50,9 @@ when their state is partitioned into fixed-size schedulable units.
   records) whether its sequences still belong here — a different answer
   migrates one sequence, pages percolating in one coalesced move.
 
-The **legacy** model contract is two callables (see ``make_paged_lm`` in
-``benchmarks/fig9_serving.py`` or ``examples/paged_serving.py``):
-
-``prefill_fn(tokens)``
-    ``(B, T) int32 -> (k, v, next)`` with k/v ``(B, L, T, K, D)`` and
-    ``next`` ``(B,) int32`` — the prompt's KV plus the first token.
-``decode_fn(k_pages, v_pages, tokens, positions, tables, lengths)``
-    one decode step over the *pools*: scatter each row's incoming
-    token's k/v into ``pages[tables[b, pos // P], pos % P]``, attend
-    through the page table (``repro.kernels.paged_attention``), return
-    ``(k_pages, v_pages, next)``.  Donating the pool args keeps the
-    update in place.
-
-The model **zoo** rides the richer ``contract="zoo"`` (DESIGN.md §17),
-wired by ``PagedServeEngine.from_config(cfg)`` from the uniform
-``repro.models.model.paged_surface`` triple:
+The model contract is two jitted steps that take their params as the
+first argument; ``PagedServeEngine.from_config(cfg)`` wires them from a
+zoo family's ``repro.models.model.paged_surface`` triple (DESIGN.md §17):
 
 ``prefill_fn(params, tokens, extras)``
     ``-> (k, v, state, last_logits)`` with k/v ``(B, L, T', K, D)`` —
@@ -79,8 +66,13 @@ wired by ``PagedServeEngine.from_config(cfg)`` from the uniform
 ``decode_fn(params, k_pages, v_pages, state, tokens, positions, tables, lengths)``
     ``-> (k_pages, v_pages, state, logits)`` — one ragged step over the
     pools plus the batch's resident state, batch-leading (the engine
-    gathers it out of the state slab on the device); ``logits`` ``(B, V)``
-    come back to the host for sampling.
+    gathers it out of the state slab on the device): scatter each row's
+    incoming token's k/v into ``pages[tables[b, pos // P], pos % P]``,
+    attend through the page table and return the ``(B, V)`` logits,
+    which come back to the host for sampling.  Donating the two slabs
+    keeps the page update in place.  ``_pool_step`` is the one place the
+    step is called; the decode lanes and the cross-locality worker alike
+    reach it through ``_decode_rows``.
 
 ``params`` is an executable argument, never a closure constant (a
 captured array lowers into the program as a constant — gigabytes per
@@ -111,23 +103,17 @@ whose state came from a slab and the slabs that grew, spilled and
 refetched pages, and the bytes copied between host and device, by stage.
 ``PagedServeEngine.counters()`` returns the totals; docs/index.md,
 "Tracing the engine", says what each one means.
-
-Env knobs: ``REPRO_PAGE_SIZE`` (tokens per page, default 16),
-``REPRO_PAGE_POOL_BYTES`` (per-device pool bytes, default 32 MiB),
-``REPRO_PREFILL_TOKEN_BUDGET`` (prefill lane batch bound, default 2048),
-``REPRO_DECODE_DEADLINE_S`` (decode lane arrival wait, default 1 ms).
 """
 from __future__ import annotations
 
 import collections
 import concurrent.futures as _cf
 import contextlib
+import dataclasses
 import functools
-import os
 import threading
 import time
 import weakref
-from dataclasses import dataclass
 from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
@@ -141,6 +127,7 @@ from repro.core.trace import Counters, span
 from repro.serving.engine import EngineClosed, LanePolicy, QueueFull
 
 __all__ = [
+    "PAGE_SIZE",
     "PageSpec",
     "PagePool",
     "PagedKVCache",
@@ -153,13 +140,6 @@ __all__ = [
 ]
 
 
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, default))
-    except ValueError:
-        return default
-
-
 def _now() -> float:
     return time.monotonic()
 
@@ -168,11 +148,14 @@ class OutOfPages(RuntimeError):
     """The pool has fewer free pages than the allocation needs."""
 
 
-@dataclass(frozen=True)
+# Tokens per KV page of every zoo family's ``paged_spec``.
+PAGE_SIZE = 16
+
+
+@dataclasses.dataclass(frozen=True)
 class PageSpec:
     """Geometry of one KV page: ``page_size`` tokens × ``kv_heads`` ×
-    ``head_dim`` per layer, k and v both.  Pass ``page_size=0`` to take
-    ``REPRO_PAGE_SIZE`` (default 16)."""
+    ``head_dim`` per layer, k and v both."""
 
     layers: int
     page_size: int
@@ -181,9 +164,8 @@ class PageSpec:
     dtype: Any = np.float32
 
     def __post_init__(self):
-        if not self.page_size:
-            object.__setattr__(
-                self, "page_size", _env_int("REPRO_PAGE_SIZE", 16))
+        if self.page_size < 1:
+            raise ValueError(f"page_size must be >= 1, got {self.page_size}")
 
     @property
     def page_bytes(self) -> int:
@@ -195,9 +177,9 @@ class PageSpec:
         return max(0, -(-int(tokens) // self.page_size))
 
 
-@dataclass(frozen=True)
+@dataclasses.dataclass(frozen=True)
 class SamplingParams:
-    """Per-request sampling knobs (zoo contract).
+    """Per-request sampling knobs.
 
     ``temperature <= 0`` means greedy argmax (the default, and the
     parity-oracle mode).  ``top_k``/``top_p`` filter the distribution
@@ -346,7 +328,7 @@ def _state_put(slab, slots, rows):
 
 
 class StateSlab:
-    """One device's resident state for one row signature (zoo contract):
+    """One device's resident state for one row signature:
     each leaf of the row with a leading axis of ``capacity`` slots, plus a
     free list of slots.  A sequence owns a slot; the decode lane gathers
     its batch's rows out of the slab and scatters the stepped rows back in
@@ -479,7 +461,7 @@ class PagePool:
             self._repin(b)
         self.lock = threading.RLock()
         self._free: "list[int]" = list(range(self.num_pages - 1, 0, -1))
-        # Resident state (zoo contract) next to the pages: one slab per
+        # Resident state next to the pages: one slab per
         # row signature, made by its first row.
         self.state_slabs: "dict[Any, StateSlab]" = {}
 
@@ -625,7 +607,7 @@ class SeqPages:
         self.seq_id = seq_id
         self.pages: "list[int]" = []
         self.length = 0
-        # Per-sequence resident state (zoo contract) — SSM recurrent state,
+        # Per-sequence resident state — SSM recurrent state,
         # conv windows, cross K/V — lives in a slot of the device's slab
         # for its row signature and rides with the pages through
         # spill/migrate/export.  Its bytes fold into ``nbytes`` so the
@@ -672,7 +654,7 @@ class SeqPages:
             return self._spilled[2] if self._spilled is not None else None
 
     def set_state(self, state, row: "int | None" = None) -> None:
-        """Write the sequence's resident state (zoo contract) into its slot
+        """Write the sequence's resident state into its slot
         on this device's slab for the row's signature.  ``state`` is one
         row, or, with ``row``, a batch-leading tree (a prefill's output)
         of which row ``row`` is this sequence's: the other rows write
@@ -781,14 +763,12 @@ class PagedKVCache:
 
     def __init__(self, spec: PageSpec, devices: "Sequence | None" = None,
                  pool_pages: "int | None" = None,
-                 pool_bytes: "int | None" = None):
+                 pool_bytes: int = 32 << 20):
         if devices is None:
             from repro.core.device import get_all_devices
 
             devices = list(get_all_devices().get())
         if pool_pages is None:
-            if pool_bytes is None:
-                pool_bytes = _env_int("REPRO_PAGE_POOL_BYTES", 32 << 20)
             pool_pages = max(2, pool_bytes // spec.page_bytes)
         self.spec = spec
         self.counters = Counters()
@@ -1078,7 +1058,7 @@ class _PagedRequest:
         self.max_new = max_new
         self.promise = promise
         self.arrived = arrived
-        # Zoo-contract identity + knobs: ``rid`` keys the sampling PRNG
+        # Identity + knobs: ``rid`` keys the sampling PRNG
         # stream, ``sampling`` is a SamplingParams (None = greedy),
         # ``extras`` carries per-request modality inputs (whisper frames).
         self.rid = rid
@@ -1096,6 +1076,15 @@ class _PagedRequest:
         self.handed_off = False
 
 
+def _filled(pol: "LanePolicy | None", **defaults) -> LanePolicy:
+    """``pol`` with each unset field taken from ``defaults``: an explicit
+    0 or 0.0 is a real bound (dispatch now), matching
+    ``RequestEngine._lane_bounds``."""
+    got = {} if pol is None else {
+        k: v for k, v in dataclasses.asdict(pol).items() if v is not None}
+    return LanePolicy(**{**defaults, **got})
+
+
 class PagedServeEngine:
     """Prefill/decode-disaggregated serving over a ``PagedKVCache``.
 
@@ -1108,20 +1097,18 @@ class PagedServeEngine:
     """
 
     def __init__(self, kv: PagedKVCache, prefill_fn: Callable, decode_fn: Callable,
-                 *, max_seq_len: int, scheduler=None,
+                 *, weights: dict, max_seq_len: int, scheduler=None,
                  prefill: "LanePolicy | None" = None,
                  decode: "LanePolicy | None" = None,
                  max_queue: int = 512, rebalance_every: int = 32,
                  decode_shapes: "Sequence[int] | None" = None,
-                 contract: str = "legacy",
-                 weights: "dict | None" = None,
                  name: str = "paged"):
-        if contract not in ("legacy", "zoo"):
-            raise ValueError(f"contract must be 'legacy' or 'zoo', got {contract!r}")
-        if contract == "zoo" and (weights is None or set(weights) != set(kv.pools)):
-            raise ValueError("the zoo contract needs one weights replica per pool device")
+        if set(weights) != set(kv.pools):
+            raise ValueError(
+                f"weights must hold one params replica per pool device "
+                f"{sorted(kv.pools)}, got {sorted(weights)}")
         self.kv = kv
-        # device key -> that device's params replica (zoo contract).
+        # device key -> that device's params replica.
         self.weights = weights
         self.prefill_fn = prefill_fn
         self.decode_fn = decode_fn
@@ -1129,7 +1116,6 @@ class PagedServeEngine:
         # set over ``decode_fn`` later (one that counts served steps) sees
         # served steps alone.
         self._built_decode_fn = decode_fn
-        self.contract = contract
         self._next_rid = 0
         # Optional row-count palette preseeded into every decode lane's
         # warm-shape set (see _DecodeLane): a closed palette (e.g. powers
@@ -1147,12 +1133,10 @@ class PagedServeEngine:
         self._scheduler = scheduler
         self.max_queue = int(max_queue)
         self.rebalance_every = max(1, int(rebalance_every))
-        self.prefill_policy = prefill if prefill is not None else LanePolicy(
-            max_batch=8, max_delay_s=0.004,
-            token_budget=_env_int("REPRO_PREFILL_TOKEN_BUDGET", 2048))
-        self.decode_policy = decode if decode is not None else LanePolicy(
-            max_batch=64,
-            max_delay_s=float(os.environ.get("REPRO_DECODE_DEADLINE_S", 0.001)))
+        self.prefill_policy = _filled(
+            prefill if prefill is not None else LanePolicy(token_budget=2048),
+            max_batch=8, max_delay_s=0.004, token_budget=1 << 30)
+        self.decode_policy = _filled(decode, max_batch=64, max_delay_s=0.001)
 
         self._cv = threading.Condition()
         self._queue: "list[_PagedRequest]" = []
@@ -1196,12 +1180,12 @@ class PagedServeEngine:
     def from_config(cls, cfg, *, devices=None, params=None, seed: int = 0,
                     max_seq_len: "int | None" = None,
                     pool_pages: "int | None" = None,
-                    pool_bytes: "int | None" = None, **kw) -> "PagedServeEngine":
+                    pool_bytes: int = 32 << 20, **kw) -> "PagedServeEngine":
         """Wire any zoo architecture (``repro.configs``) into a paged
         engine: one ``PageSpec`` from ``paged_spec`` (multi-layer KV
         folded into one slab geometry), a jitted prefill and a jitted
         slab-donating decode step from ``paged_prefill`` /
-        ``paged_decode_step``, ``contract="zoo"``.  ``params`` defaults
+        ``paged_decode_step``.  ``params`` defaults
         to ``init(cfg, PRNGKey(seed))`` — two localities building from
         the same seed hold bit-identical weights, which is what lets a
         shipped sequence resume decoding elsewhere.  The weights are
@@ -1220,8 +1204,7 @@ class PagedServeEngine:
         weights = {key: jax.device_put(params, pool.device.jax_device)
                    for key, pool in kv.pools.items()}
         kw.setdefault("name", f"paged-{getattr(cfg, 'name', cfg.family)}")
-        return cls(kv, pre, dec, max_seq_len=int(max_seq_len),
-                   contract="zoo", weights=weights, **kw)
+        return cls(kv, pre, dec, weights=weights, max_seq_len=int(max_seq_len), **kw)
 
     # -- submission ----------------------------------------------------------
 
@@ -1229,7 +1212,7 @@ class PagedServeEngine:
                sampling: "SamplingParams | None" = None,
                extras: "dict | None" = None,
                request_id: "int | None" = None) -> Future:
-        """Queue one request.  ``sampling`` (zoo contract) selects the
+        """Queue one request.  ``sampling`` selects the
         host-side sampler (None = greedy); ``extras`` carries modality
         inputs (e.g. whisper ``frames``); ``request_id`` keys the
         sampling PRNG stream — pass an explicit, fleet-stable id when
@@ -1336,16 +1319,9 @@ class PagedServeEngine:
                 if not self._queue:
                     return
                 head = self._queue[0]
-                # `x if x is not None else d`, never `x or d`: an explicit
-                # 0.0 deadline / 0 budget is a real policy (dispatch now),
-                # matching RequestEngine._lane_bounds.
-                delay = pol.max_delay_s if pol.max_delay_s is not None else 0.004
-                deadline = head.arrived + delay
+                deadline = head.arrived + pol.max_delay_s
                 T = head.tokens.size
-                budget = pol.token_budget if pol.token_budget is not None else 1 << 30
-                budget_rows = max(1, budget // max(T, 1))
-                cap = min(pol.max_batch if pol.max_batch is not None else 8,
-                          budget_rows)
+                cap = min(pol.max_batch, max(1, pol.token_budget // max(T, 1)))
                 while (not self._closed and _now() < deadline
                        and sum(1 for r in self._queue if r.tokens.size == T) < cap):
                     self._cv.wait(timeout=max(deadline - _now(), 0.0005))
@@ -1372,18 +1348,13 @@ class PagedServeEngine:
                         self._prefill_done(r)
 
     def _run_prefill(self, group: "list[_PagedRequest]") -> None:
-        """Place every request of an equal-length group, then prefill.  Zoo
-        steps run each device's share of the group on that device, with
-        its weights replica; the legacy toy steps carry their own weights
-        and run the whole group at once."""
+        """Place every request of an equal-length group, then prefill each
+        device's share of the group on that device, with its weights
+        replica."""
         sched = self._scheduler_for()
         homes = [self._prefill_home(sched.select(args=())) for _ in group]
-        if self.contract == "legacy":
-            self._prefill_on(group, homes)
-            return
         for home in dict.fromkeys(homes):
-            share = [i for i, h in enumerate(homes) if h is home]
-            self._prefill_on([group[i] for i in share], [home] * len(share))
+            self._prefill_on([r for r, h in zip(group, homes) if h is home], home)
 
     def _prefill_home(self, dev):
         """The device a placed request prefills on: the scheduler's pick
@@ -1394,7 +1365,7 @@ class PagedServeEngine:
             pool = max(self.kv.pools.values(), key=lambda p: p.num_free)
         return pool.device
 
-    def _prefill_on(self, group: "list[_PagedRequest]", homes: list) -> None:
+    def _prefill_on(self, group: "list[_PagedRequest]", home) -> None:
         c = self._counters
         launched = _now()
         for r in group:
@@ -1402,32 +1373,25 @@ class PagedServeEngine:
         c.add("admitted", len(group))
         c.add("admission_wait_s", sum(launched - r.arrived for r in group))
         batch = np.stack([r.tokens for r in group])  # (B, T) — equal-T: no padding
-        state = extras = None
+        extras = None
         rid = group[0].rid
-        if self.contract == "zoo" and group[0].extras is not None:
+        if group[0].extras is not None:
             extras = {key: np.stack([np.asarray(r.extras[key]) for r in group])
                       for key in group[0].extras}
         _sent(c, "prefill_tokens", (batch, extras))
         with span("paged.prefill.step", rid=rid):
-            if self.contract == "zoo":
-                # Committed inputs put the computation on ``home``, next to
-                # that device's weights replica.  The state stays there.
-                home = homes[0]  # one device per zoo call (see _run_prefill)
-                on_home = jax.device_put((batch, extras), home.jax_device)
-                k, v, state, logits = self.prefill_fn(self.weights[home.key], *on_home)
-                logits = _fetched(c, "logits", logits)
-            else:
-                k, v, nxt = self.prefill_fn(batch)
-                nxt = np.asarray(_fetched(c, "logits", nxt), np.int32)
+            # Committed inputs put the computation on ``home``, next to
+            # that device's weights replica.  The state stays there.
+            on_home = jax.device_put((batch, extras), home.jax_device)
+            k, v, state, logits = self.prefill_fn(self.weights[home.key], *on_home)
+            logits = _fetched(c, "logits", logits)
         c.add("prefill_tokens", batch.size)
         with span("paged.prefill.kv_to_host", rid=rid):
             k, v = _fetched(c, "prompt_kv", (k, v))
-        if self.contract == "zoo":
-            # First token samples host-side at position 0 of each
-            # request's own PRNG stream — batch composition cannot leak.
-            nxt = np.asarray(
-                [sample_token(logits[i], r.sampling, r.rid, 0)
-                 for i, r in enumerate(group)], np.int32)
+        # First token samples host-side at position 0 of each request's
+        # own PRNG stream — batch composition cannot leak.
+        nxt = [sample_token(logits[i], r.sampling, r.rid, 0)
+               for i, r in enumerate(group)]
         # Page k.shape[2] tokens, not the prompt length: hybrid archs
         # prepend meta/register tokens whose KV pages in with the prompt.
         Tp = k.shape[2]
@@ -1435,14 +1399,14 @@ class PagedServeEngine:
             self._prefill_batches += 1
             self._prefill_rows += len(group)
         for i, req in enumerate(group):
-            pool = self._pool_with_room(homes[i], self.kv.spec.pages_for(Tp) + 1)
+            pool = self._pool_with_room(home, self.kv.spec.pages_for(Tp) + 1)
             req.seq = self.kv.new_seq(pool.device)
             # k[i]: (L, T', Kh, D) — the whole prompt pages in as one write.
             self.kv.append(req.seq, k[i], v[i])
             if state is not None:
                 self._warm_state_path(pool, state)
                 req.seq.set_state(state, row=i)
-            req.out.append(int(nxt[i]))
+            req.out.append(nxt[i])
             req.token_times.append(_now())
             c.add("output_tokens", 1)
             if req.max_new <= 1:
@@ -1459,9 +1423,8 @@ class PagedServeEngine:
         and the scatter.  A device tree in the state's place compiles a decode
         executable apart from one warmed with host stacks, so this lands
         with the first stateful prefill rather than in a decode step."""
-        cap = self.decode_policy.max_batch
         slab = pool.state_slab(_row_signature(state),
-                               capacity=cap if cap is not None else 64)
+                               capacity=self.decode_policy.max_batch)
         if slab.warm_rows or not self.decode_shapes:
             return
         weights = self.weights[pool.device.key]
@@ -1471,11 +1434,8 @@ class PagedServeEngine:
             tbl = np.zeros((n, self.max_pages), np.int32)
             lens = np.zeros((n,), np.int32)
             with pool.lock:
-                ks, vs = pool.arrays()
-                k2, v2, st2, _ = self._built_decode_fn(
-                    weights, ks, vs, st, np.ones((n,), np.int32), lens, tbl, lens)
-                pool.set_arrays(k2, v2)
-            return st2
+                return _pool_step(pool, self._built_decode_fn, weights, st,
+                                  np.ones((n,), np.int32), tbl, lens)[0]
 
         slab.warm(self.decode_shapes, step)
 
@@ -1606,6 +1566,47 @@ class PagedServeEngine:
                 f"{self._submitted} sequences)")
 
 
+def _pool_step(pool: PagePool, step_fn: Callable, params, state, tokens,
+               tables, lengths, fetch: "Callable | None" = None):
+    """The one call of a model decode step, over ``pool``'s slabs (the
+    caller holds ``pool.lock``): ``fetch``, where given, takes the logits
+    to the host before the stepped slabs swap in.  Returns the stepped
+    state and the logits."""
+    ks, vs = pool.arrays()
+    k2, v2, state, logits = step_fn(
+        params, ks, vs, state, tokens, lengths, tables, lengths)
+    if fetch is not None:
+        logits = fetch(logits)  # sync before the slabs swap
+    pool.set_arrays(k2, v2)
+    return state, logits
+
+
+def _decode_rows(kv: PagedKVCache, pool: PagePool, step_fn: Callable, params,
+                 seqs: "Sequence[SeqPages]", tokens, tables, lengths,
+                 rows: int, *, step: int) -> np.ndarray:
+    """One model decode step over ``seqs`` on ``pool``'s device, the
+    operands padded to ``rows`` rows; returns the ``(rows, V)`` logits on
+    the host.  Gathers the rows' resident state out of their slab (pad
+    rows repeat the last row, and write nothing on the way back in), runs
+    ``step_fn`` over the pool's slabs under ``pool.lock``, fetches the
+    logits before the stepped slabs swap in, and scatters the stepped
+    state back.  The caller has grown every tail by a slot, and
+    ``note_decoded`` is its to call."""
+    c = kv.counters
+    state = None
+    if seqs[0]._slot is not None:
+        with span("paged.decode.state_in", step=step):
+            state = kv.state_rows(seqs, rows)
+        c.add("state_rows_on_device", len(seqs))
+    with pool.lock, span("paged.decode.step", step=step):
+        state, logits = _pool_step(pool, step_fn, params, state, tokens, tables,
+                                   lengths, fetch=lambda a: _fetched(c, "logits", a))
+    if state is not None:
+        with span("paged.decode.state_out", step=step):
+            kv.put_state_rows(seqs, state)
+    return logits
+
+
 class _DecodeLane:
     """One device's decode lane: continuous exact-row batched stepping.
 
@@ -1675,10 +1676,8 @@ class _DecodeLane:
                     continue
                 if not self._active and self._inbox:
                     # Idle lane: give the batch one deadline window to
-                    # fill (an explicit 0.0 means dispatch immediately —
-                    # `is not None`, matching RequestEngine._lane_bounds).
-                    delay = pol.max_delay_s if pol.max_delay_s is not None else 0.001
-                    deadline = _now() + delay
+                    # fill (0.0 dispatches immediately).
+                    deadline = _now() + pol.max_delay_s
                     while not self._closed and _now() < deadline:
                         self._cv.wait(timeout=max(deadline - _now(), 0.0005))
                 self._active.extend(self._inbox)
@@ -1688,8 +1687,7 @@ class _DecodeLane:
                 # and putting it ahead of resident work would let one
                 # unfittable sequence stall the whole lane.
                 self._active.sort(key=lambda r: r.seq.spilled)
-                cap = pol.max_batch if pol.max_batch is not None else 64
-                batch = self._active[:cap]
+                batch = self._active[:pol.max_batch]
                 active = len(self._active)
             if not batch:
                 continue
@@ -1774,43 +1772,19 @@ class _DecodeLane:
             # dispatch path moves four tiny arrays faster than four
             # python-level device_put round-trips would.
             _sent(c, "decode_operands", (tokens, lens, tbl, lens))
-            pool = kv.pool_of(self.device)
-            if eng.contract == "zoo":
-                # Gather the rows' resident state out of their slab on the
-                # device (pad rows repeat the last row, and write nothing
-                # on the way back in).
-                state = None
-                if seqs[0]._slot is not None:
-                    with span("paged.decode.state_in", step=step):
-                        state = kv.state_rows(seqs, want)
-                    c.add("state_rows_on_device", b_real)
-                with pool.lock, span("paged.decode.step", step=step):
-                    ks, vs = pool.arrays()
-                    k2, v2, st2, logits = eng.decode_fn(
-                        eng.weights[self.device.key], ks, vs, state, tokens,
-                        lens, tbl, lens)
-                    # sync before the slabs swap
-                    logits = _fetched(c, "logits", logits)
-                    pool.set_arrays(k2, v2)
-                if st2 is not None:
-                    with span("paged.decode.state_out", step=step):
-                        kv.put_state_rows(seqs, st2)
-                with span("paged.decode.sample", step=step):
-                    # Position = tokens already emitted (prefill's token
-                    # was position 0): identity-keyed, batch-independent.
-                    nxt = [sample_token(logits[i], r.sampling, r.rid, len(r.out))
-                           for i, r in enumerate(batch)]
-            else:
-                with pool.lock, span("paged.decode.step", step=step):
-                    ks, vs = pool.arrays()
-                    k2, v2, nxt = eng.decode_fn(ks, vs, tokens, lens, tbl, lens)
-                    # sync before the slabs swap
-                    nxt = np.asarray(_fetched(c, "logits", nxt), np.int32)
-                    pool.set_arrays(k2, v2)
+            logits = _decode_rows(
+                kv, kv.pool_of(self.device), eng.decode_fn,
+                eng.weights[self.device.key], seqs, tokens, tbl, lens, want,
+                step=step)
+            with span("paged.decode.sample", step=step):
+                # Position = tokens already emitted (prefill's token was
+                # position 0): identity-keyed, batch-independent.
+                nxt = [sample_token(logits[i], r.sampling, r.rid, len(r.out))
+                       for i, r in enumerate(batch)]
             now = _now()
             for i, r in enumerate(batch):
                 kv.note_decoded(r.seq)
-                r.out.append(int(nxt[i]))
+                r.out.append(nxt[i])
                 r.token_times.append(now)
                 if len(r.out) >= r.max_new:
                     done.append(r)
@@ -1961,19 +1935,12 @@ def paged_worker_decode(payload: dict) -> np.ndarray:
     max_pages = int(payload["max_pages"])
     out = [int(payload["first_token"])]
     try:
-        for _ in range(int(payload["max_new"]) - 1):
+        for step in range(int(payload["max_new"]) - 1):
             kv.ensure_slot(seq)
             tbl, lens = kv.table([seq], max_pages)
             tokens = np.asarray([out[-1]], np.int32)
-            state = kv.state_rows([seq], 1)
-            with pool.lock:
-                ks, vs = pool.arrays()
-                k2, v2, st2, logits = ctx["dec"](
-                    ctx["params"], ks, vs, state, tokens, lens, tbl, lens)
-                logits = np.asarray(logits)
-                pool.set_arrays(k2, v2)
-            if st2 is not None:
-                kv.put_state_rows([seq], st2)
+            logits = _decode_rows(kv, pool, ctx["dec"], ctx["params"], [seq],
+                                  tokens, tbl, lens, 1, step=step)
             kv.note_decoded(seq)
             out.append(sample_token(logits[0], sp, rid, len(out)))
     finally:

@@ -258,12 +258,10 @@ def test_alloc_free_defrag_invariants(seed):
     assert kv.pools[device.key].used_pages == 0
 
 
-def test_page_size_env_default(monkeypatch):
-    monkeypatch.setenv("REPRO_PAGE_SIZE", "8")
-    assert PageSpec(1, 0, 2, 4).page_size == 8
-    monkeypatch.delenv("REPRO_PAGE_SIZE")
-    assert PageSpec(1, 0, 2, 4).page_size == 16
-    assert PageSpec(1, 4, 2, 4).page_size == 4  # explicit wins
+def test_page_size_is_explicit():
+    with pytest.raises(ValueError, match="page_size"):
+        PageSpec(1, 0, 2, 4)
+    assert PageSpec(1, 4, 2, 4).page_size == 4
 
 
 def test_pool_overflow_and_double_free(device):
@@ -470,37 +468,45 @@ def test_padding_waste_reported(device):
 
 
 def _toy_paged_model(V=64, K=1, D=4, P=4):
-    """Deterministic LM: next token = (last + 1) % V, but the KV pools and
-    the paged-attention gather are genuinely exercised (a masking or
-    table bug turns the output non-finite, failing the assert)."""
+    """Deterministic LM on the engine's contract, its params an embedding
+    table: the logits are one-hot at (last + 1) % V, so greedy sampling
+    serves exactly that token, but the KV pools and the paged-attention
+    gather are genuinely exercised (a masking or table bug turns the
+    attention output non-finite, which moves the hot logit one token on,
+    failing the assert).  Returns ``(prefill_fn, decode_fn, emb)``."""
     emb = jnp.asarray(np.random.default_rng(0).normal(size=(V, K, D)).astype(np.float32))
 
-    def prefill_fn(tokens):
-        tokens = jnp.asarray(tokens)
-        e = emb[tokens]  # (B, T, K, D)
-        return e[:, None], e[:, None], (tokens[:, -1] + 1) % V
+    @jax.jit
+    def prefill_fn(params, tokens, extras):
+        e = params[tokens]  # (B, T, K, D)
+        return e[:, None], e[:, None], None, jax.nn.one_hot((tokens[:, -1] + 1) % V, V)
 
     @jax.jit
-    def decode_fn(kp, vp, tokens, positions, tables, lengths):
-        e = emb[tokens]
+    def decode_fn(params, kp, vp, state, tokens, positions, tables, lengths):
+        e = params[tokens]
         b = tokens.shape[0]
         page = tables[jnp.arange(b), positions // P]
         slot = positions % P
         kp = kp.at[0, page, slot].set(e)
         vp = vp.at[0, page, slot].set(e)
         o = paged_attention_ref(e.reshape(b, K, D), kp[0], vp[0], tables, lengths + 1)
-        guard = jnp.where(jnp.isfinite(o.sum(axis=(1, 2))), 0, 1 << 20).astype(jnp.int32)
-        return kp, vp, (tokens + 1) % V + guard
+        guard = jnp.where(jnp.isfinite(o.sum(axis=(1, 2))), 0, 1)
+        return kp, vp, None, jax.nn.one_hot((tokens + 1 + guard) % V, V)
 
-    return prefill_fn, decode_fn
+    return prefill_fn, decode_fn, emb
+
+
+def _replicas(params, devices):
+    """One params replica per pool device, keyed by device key."""
+    return {d.key: jax.device_put(params, d.jax_device) for d in devices}
 
 
 def test_paged_engine_serves_mixed_lengths_with_zero_padding(device):
     V, P = 64, 4
-    prefill_fn, decode_fn = _toy_paged_model(V=V, P=P)
+    prefill_fn, decode_fn, emb = _toy_paged_model(V=V, P=P)
     kv = PagedKVCache(PageSpec(1, P, 1, 4), devices=[device], pool_pages=64)
     eng = PagedServeEngine(
-        kv, prefill_fn, decode_fn, max_seq_len=32,
+        kv, prefill_fn, decode_fn, weights=_replicas(emb, [device]), max_seq_len=32,
         scheduler=Scheduler([device]), name="t-paged",
     )
     rng = np.random.default_rng(3)
@@ -525,11 +531,21 @@ def test_paged_engine_serves_mixed_lengths_with_zero_padding(device):
     assert kv.pools[device.key].used_pages == 0  # all pages back
 
 
-def test_paged_engine_admission_guards(device):
-    prefill_fn, decode_fn = _toy_paged_model()
+def test_paged_engine_needs_one_weights_replica_per_pool(device):
+    prefill_fn, decode_fn, emb = _toy_paged_model()
     kv = PagedKVCache(PageSpec(1, 4, 1, 4), devices=[device], pool_pages=16)
-    eng = PagedServeEngine(kv, prefill_fn, decode_fn, max_seq_len=16,
-                           scheduler=Scheduler([device]), name="t-guard")
+    with pytest.raises(TypeError, match="weights"):
+        PagedServeEngine(kv, prefill_fn, decode_fn, max_seq_len=16)
+    with pytest.raises(ValueError, match="one params replica per pool device"):
+        PagedServeEngine(kv, prefill_fn, decode_fn, weights={"tpu:99": emb},
+                         max_seq_len=16)
+
+
+def test_paged_engine_admission_guards(device):
+    prefill_fn, decode_fn, emb = _toy_paged_model()
+    kv = PagedKVCache(PageSpec(1, 4, 1, 4), devices=[device], pool_pages=16)
+    eng = PagedServeEngine(kv, prefill_fn, decode_fn, weights=_replicas(emb, [device]),
+                           max_seq_len=16, scheduler=Scheduler([device]), name="t-guard")
     try:
         with pytest.raises(ValueError, match="empty prompt"):
             eng.submit(np.zeros((0,), np.int32), 4)
@@ -569,10 +585,10 @@ def test_paged_engine_exact_tokens_under_spill_pressure(device):
     an unpinned sequence's pages could be freed and re-owned mid-step):
     every generated token must still be exact."""
     V, P = 64, 4
-    prefill_fn, decode_fn = _toy_paged_model(V=V, P=P)
+    prefill_fn, decode_fn, emb = _toy_paged_model(V=V, P=P)
     kv = PagedKVCache(PageSpec(1, P, 1, 4), devices=[device], pool_pages=64)
-    eng = PagedServeEngine(kv, prefill_fn, decode_fn, max_seq_len=32,
-                           scheduler=Scheduler([device]), name="t-spillrace")
+    eng = PagedServeEngine(kv, prefill_fn, decode_fn, weights=_replicas(emb, [device]),
+                           max_seq_len=32, scheduler=Scheduler([device]), name="t-spillrace")
     stop = threading.Event()
 
     def spiller():
@@ -659,10 +675,10 @@ def test_prefill_partial_failure_fails_only_unadmitted(device):
     still owns: already-admitted members finish normally, the lane thread
     survives (no double settlement), drain() returns, no page leaks."""
     V, P = 64, 4
-    prefill_fn, decode_fn = _toy_paged_model(V=V, P=P)
+    prefill_fn, decode_fn, emb = _toy_paged_model(V=V, P=P)
     kv = PagedKVCache(PageSpec(1, P, 1, 4), devices=[device], pool_pages=64)
     eng = PagedServeEngine(
-        kv, prefill_fn, decode_fn, max_seq_len=32,
+        kv, prefill_fn, decode_fn, weights=_replicas(emb, [device]), max_seq_len=32,
         scheduler=Scheduler([device]),
         prefill=LanePolicy(max_batch=8, max_delay_s=0.25, token_budget=4096),
         name="t-partial")
@@ -703,10 +719,10 @@ def test_lane_policy_explicit_zero_not_treated_as_unset(device):
     """LanePolicy(token_budget=0) / max_delay_s=0.0 are real bounds, not
     'inherit the default' (matching RequestEngine._lane_bounds)."""
     V, P = 64, 4
-    prefill_fn, decode_fn = _toy_paged_model(V=V, P=P)
+    prefill_fn, decode_fn, emb = _toy_paged_model(V=V, P=P)
     kv = PagedKVCache(PageSpec(1, P, 1, 4), devices=[device], pool_pages=64)
     eng = PagedServeEngine(
-        kv, prefill_fn, decode_fn, max_seq_len=32,
+        kv, prefill_fn, decode_fn, weights=_replicas(emb, [device]), max_seq_len=32,
         scheduler=Scheduler([device]),
         prefill=LanePolicy(max_batch=8, max_delay_s=0.05, token_budget=0),
         decode=LanePolicy(max_batch=64, max_delay_s=0.0),
@@ -735,7 +751,7 @@ _FLEET = textwrap.dedent("""
     import numpy as np, jax, jax.numpy as jnp
     from repro.core import Scheduler, get_all_devices, agas
     from repro.serving.paged import PagedKVCache, PagedServeEngine, PageSpec
-    from tests.test_paged import _toy_paged_model, _fill, _spec, _seq_tokens
+    from tests.test_paged import _toy_paged_model, _replicas, _fill, _spec, _seq_tokens
 
     devs = list(get_all_devices().get())
     assert len(devs) == 4
@@ -756,11 +772,11 @@ _FLEET = textwrap.dedent("""
 
     # -- engine spreads sequences over the fleet, zero padding waste
     V, P = 64, 4
-    prefill_fn, decode_fn = _toy_paged_model(V=V, P=P)
+    prefill_fn, decode_fn, emb = _toy_paged_model(V=V, P=P)
     kv = PagedKVCache(PageSpec(1, P, 1, 4), devices=devs, pool_pages=64)
     sched = Scheduler(devs, policy="least_loaded")
-    eng = PagedServeEngine(kv, prefill_fn, decode_fn, max_seq_len=32,
-                           scheduler=sched, name="fleet")
+    eng = PagedServeEngine(kv, prefill_fn, decode_fn, weights=_replicas(emb, devs),
+                           max_seq_len=32, scheduler=sched, name="fleet")
     rng = np.random.default_rng(0)
     futs = []
     for i in range(16):

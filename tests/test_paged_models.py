@@ -29,7 +29,7 @@ from repro.core import agas, get_all_devices
 from repro.models.model import get_model, paged_surface
 from repro.serving import PagedKVCache, PagedServeEngine, PageSpec, SamplingParams
 
-PAGE = 16          # REPRO_PAGE_SIZE default; PageSpec(page_size=0) resolves to it
+PAGE = 16          # repro.serving.paged.PAGE_SIZE, every family's paged_spec page size
 MAX_PAGES = 3
 MAX_SEQ = MAX_PAGES * PAGE   # oracle cache width == engine table width * P
 # partial page / straddles a boundary mid-decode / straddles at prefill
